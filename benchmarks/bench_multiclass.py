@@ -3,18 +3,13 @@
   MPI-CUDA          -> vmapped/sharded parallel SMO over all 36 tasks
   Multi-Tensorflow  -> sequential GD, one "session" per task
 
-Also reports the distributed (shard_map, forced multi-device) variant in
-a subprocess — the actual MPI analogue — and its scaling vs worker count,
-plus ``bucketed()``: padded vs size-bucketed scheduler wall time and
-padded-FLOP fraction on an imbalanced dataset (JSON lines via
-``common.emit_json``).
+Also reports the distributed (shard_map) variant — the actual MPI
+analogue — and its scaling vs worker count, in this process over the
+first w visible devices, plus ``bucketed()``: padded vs size-bucketed
+scheduler wall time and padded-FLOP fraction on an imbalanced dataset
+(JSON lines via ``common.emit_json``).
 """
 from __future__ import annotations
-
-import os
-import subprocess
-import sys
-import textwrap
 
 import numpy as np
 import jax
@@ -24,6 +19,7 @@ from benchmarks.common import emit, emit_json, timeit
 from repro.core import dist, kernels as K, multiclass as MC, ovo
 from repro.data import load_pavia_like, make_imbalanced_blobs, normalize
 from repro.data.pipeline import subsample_per_class
+from repro.launch.mesh import make_local_mesh
 
 GD_STEPS = 2000
 
@@ -56,37 +52,31 @@ def main():
              f"tasks={ovo.n_binary_tasks(9)}")
 
 
-_SCALING = textwrap.dedent("""
-    import os, time, sys
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=%d"
-    sys.path.insert(0, "src"); sys.path.insert(0, ".")
-    import numpy as np, jax, jax.numpy as jnp
-    from repro.core import ovo, dist, kernels as K
-    from repro.data import load_pavia_like, normalize
+def scaling(workers=(1, 2, 4)):
+    """Worker-scaling of the shard_map MPI layer: 36 tasks over a mesh
+    of the first w visible devices, all in this process (a child
+    process could not take a chip this one holds). Worker counts above
+    the visible device count are skipped; on a CPU host force devices
+    with XLA_FLAGS=--xla_force_host_platform_device_count=N before JAX
+    starts. Forced host 'devices' share the same CPU, so there wall time
+    does NOT drop — the check is that the distribution overhead stays
+    ~0 (the paper's 'communication only at the ends')."""
+    print("# MPI-layer scaling (36 tasks over P workers, shard_map)")
     x, y = load_pavia_like(n_per_class=100)
     x = normalize(x)
     kp = K.resolve_gamma(K.KernelParams(), jnp.asarray(x))
-    mesh = jax.make_mesh((%d,), ("workers",))
-    tasks = ovo.build_tasks(x, y, pad_tasks_to=%d)
-    f = lambda: jax.block_until_ready(dist.distributed_ovo_fit(
-        tasks, mesh, ("workers",), solver="smo", kernel=kp).alpha)
-    f()
-    t0 = time.perf_counter(); f(); print(time.perf_counter() - t0)
-""")
-
-
-def scaling(workers=(1, 2, 4)):
-    """Worker-scaling of the shard_map MPI layer (subprocesses: device
-    count locks at jax init). Note: forced host 'devices' share the same
-    CPU, so wall time does NOT drop — the check is that the distribution
-    overhead stays ~0 (the paper's 'communication only at the ends')."""
-    print("# MPI-layer scaling (36 tasks over P workers, shard_map)")
+    taskset = MC.get_strategy("ovo").build_taskset(x, y)
     base = None
     for w in workers:
-        r = subprocess.run(
-            [sys.executable, "-c", _SCALING % (w, w, w)],
-            capture_output=True, text=True, timeout=900)
-        t = float(r.stdout.strip().splitlines()[-1])
+        if w > len(jax.devices()):
+            print(f"# skip {w} workers: {len(jax.devices())} devices")
+            continue
+        mesh = make_local_mesh(w)
+        sched = MC.build_schedule(taskset.sizes,
+                                  MC.ScheduleConfig(n_workers=w))
+        t = timeit(lambda: dist.fit_taskset(taskset, sched, mesh=mesh,
+                                            kernel=kp).alpha,
+                   warmup=1, iters=1)
         base = base or t
         emit(f"dist_ovo_workers_{w}", t, f"rel={t / base:.2f}")
 

@@ -95,6 +95,8 @@ def main(argv=None) -> None:
                          "scheduler,sharded,svr,serving,serving_load,"
                          "tile_sweep,cascade")
     args = ap.parse_args(argv)
+    from repro.launch import compile_cache
+    compile_cache.enable(_REPO_ROOT)
 
     only = set(args.only.split(",")) if args.only else None
     print("name,us_per_call,derived")

@@ -18,10 +18,10 @@ sys.path.insert(0, "src")
 import time
 
 import numpy as np
-import jax
 
 from repro.core import dist, kernels as K, multiclass as MC, ovo
 from repro.core.svm import SVC
+from repro.launch.mesh import make_local_mesh
 from repro.data import (load_pavia_like, make_imbalanced_blobs, normalize,
                         train_test_split)
 
@@ -31,7 +31,7 @@ def main():
     x = normalize(x)
     xtr, ytr, xte, yte = train_test_split(x, y, test_frac=0.2, seed=0)
 
-    mesh = jax.make_mesh((N_WORKERS,), ("workers",))
+    mesh = make_local_mesh(N_WORKERS)
     c_tasks = ovo.n_binary_tasks(9)
     print(f"9 classes -> {c_tasks} binary tasks over {N_WORKERS} workers "
           f"(N = C/P = {-(-c_tasks // N_WORKERS)} tasks/worker)")

@@ -126,7 +126,8 @@ class ShapeKeyedJit(Rule):
 
 # --------------------------------------------------------------- R002
 # the certified f64 recompute sites: full-precision KKT certificates
-_R002_CERTIFIED_FILES = ("core/cascade.py",)
+# and the float64 oracle served decisions are held to
+_R002_CERTIFIED_FILES = ("core/cascade.py", "serve/reference.py")
 _R002_CERTIFIED_FUNCS = ("kkt_violation",)
 _MATMUL_CALLS = ("jax.lax.dot_general", "lax.dot_general", "jnp.dot",
                  "jnp.matmul", "jnp.einsum", "pl.dot", "pltpu.dot")

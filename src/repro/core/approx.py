@@ -86,16 +86,16 @@ class NystromMap:
 
     def transform(self, z: jax.Array) -> jax.Array:
         z = jnp.asarray(z, jnp.float32)
-        return self._gram_fn(z, self.landmarks) @ self.proj
+        return K.f32_dot(self._gram_fn(z, self.landmarks), self.proj)
 
 
 class RFFMap:
     """``φ(z) = sqrt(2/k) cos(z Ω + phase)`` — RBF only.
 
     ``fused=None`` routes the transform through the Pallas feature-map
-    kernel on TPU and the jnp reference path elsewhere (the Pallas
-    interpreter on CPU is a correctness tool, not a fast path);
-    ``True``/``False`` force it either way.
+    kernel on a TPU (``kernels.ops.on_tpu``) and the jnp reference path
+    elsewhere (the Pallas interpreter on CPU is a correctness tool, not
+    a fast path); ``True``/``False`` force it either way.
     """
 
     kind = "rff"
@@ -126,16 +126,14 @@ class RFFMap:
         return math.sqrt(2.0 / self.rank)
 
     def transform(self, z: jax.Array) -> jax.Array:
+        from repro.kernels import ops
         z = jnp.asarray(z, jnp.float32)
-        fused = self.fused
-        if fused is None:
-            fused = jax.default_backend() == "tpu"
+        fused = ops.on_tpu() if self.fused is None else self.fused
         if fused:
-            from repro.kernels import ops
             return ops.rff_features(z, self.omega, self.phase,
                                     scale=self.scale,
                                     compute_dtype=self.gram_dtype)
-        return self.scale * jnp.cos(z @ self.omega + self.phase)
+        return self.scale * jnp.cos(K.f32_dot(z, self.omega) + self.phase)
 
 
 def map_from_arrays(kind: str, kernel: K.KernelParams, a, b,
@@ -255,7 +253,7 @@ class LowRankKernelEngine(KE.KernelEngine):
                 f"LowRankKernelEngine.full(): refusing to materialize a "
                 f"({self.n}, {self.n}) approximate Gram (dense_limit="
                 f"{self.cfg.dense_limit}); use row()/block()/matvec()")
-        return self.phi @ self.phi.T
+        return K.f32_dot(self.phi, self.phi.T)
 
     def diag(self):
         # the APPROXIMATE diagonal |phi_i|^2, not the exact K(x_i, x_i):
@@ -263,16 +261,17 @@ class LowRankKernelEngine(KE.KernelEngine):
         return jnp.sum(self.phi * self.phi, axis=1)
 
     def row(self, i, cache=None):
-        return self.phi @ self.phi[i], cache
+        return K.f32_dot(self.phi, self.phi[i]), cache
 
     def block(self, rows, cols):
-        return self.phi[rows] @ self.phi[cols].T
+        return K.f32_dot(self.phi[rows], self.phi[cols].T)
 
     def cross(self, z):
-        return self.fmap.transform(z) @ self.phi.T
+        return K.f32_dot(self.fmap.transform(z), self.phi.T)
 
     def matvec(self, v):
-        return self.phi @ (self.phi.T @ v)
+        return K.f32_dot(self.phi, K.f32_dot(self.phi.T, v))
 
     def decide(self, z, coef, b=0.0):
-        return self.fmap.transform(z) @ (self.phi.T @ coef) + b
+        return K.f32_dot(self.fmap.transform(z),
+                         K.f32_dot(self.phi.T, coef)) + b

@@ -62,9 +62,6 @@ from repro.core import multiclass as MC
 from repro.core import smo as smo_mod
 from repro.core.ovo import OvOTasks
 
-# version-compat shard_map wrapper now lives next to the sharded engine
-_shard_map = KE.shard_map_compat
-
 # fit_taskset(shard="auto") sends a bucket data-parallel only when its
 # tasks are wide enough to amortize the per-iteration collectives AND too
 # few to keep every worker busy under task parallelism
@@ -204,8 +201,10 @@ def _sharded_fit_many(mesh, worker_axes, solver, smo_cfg, gd_cfg, kernel,
                         svr_epsilon=svr_epsilon)
     spec = P(worker_axes)
     n_in = 4 if warm else 3
-    return jax.jit(_shard_map(fit_local, mesh, (spec,) * n_in,
-                              OvOFit(spec, spec, spec, spec)))
+    return jax.jit(jax.shard_map(fit_local, mesh=mesh,
+                                 in_specs=(spec,) * n_in,
+                                 out_specs=OvOFit(spec, spec, spec, spec),
+                                 check_vma=False))
 
 
 class TaskSetFit(NamedTuple):

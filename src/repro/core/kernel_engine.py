@@ -131,25 +131,6 @@ import jax.numpy as jnp
 
 from repro.core import kernels as K
 
-try:  # jax >= 0.6 exposes shard_map at top level
-    _shard_map_fn = jax.shard_map
-except AttributeError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map_fn
-
-
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """Version-compat shard_map: the replication-check kwarg was renamed
-    (``check_rep`` on jax 0.4/0.5, ``check_vma`` on jax >= 0.6); calling
-    with the wrong one is a TypeError. Shared by ``core.dist`` (task
-    sharding) and ``core.smo.sharded_binary_smo`` (sample sharding)."""
-    try:
-        return _shard_map_fn(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    except TypeError:
-        return _shard_map_fn(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_rep=False)
-
-
 class RowCache(NamedTuple):
     """Functional LRU row-cache state (threaded through solver loops)."""
 
@@ -247,7 +228,7 @@ class KernelEngine:
         pad = (-t) % chunk
         zp = jnp.pad(z, ((0, pad), (0, 0)))
         blocks = zp.reshape(-1, chunk, z.shape[1])
-        out = jax.lax.map(lambda zb: self.cross(zb) @ coef, blocks)
+        out = jax.lax.map(lambda zb: K.f32_dot(self.cross(zb), coef), blocks)
         return out.reshape(-1)[:t] + b
 
     def init_cache(self):
@@ -277,7 +258,7 @@ class DenseKernelEngine(KernelEngine):
         return self.gram[rows][:, cols]
 
     def matvec(self, v):
-        return self.gram @ v
+        return K.f32_dot(self.gram, v)
 
 
 class ChunkedKernelEngine(KernelEngine):
@@ -340,7 +321,8 @@ class ChunkedKernelEngine(KernelEngine):
 
     def matvec(self, v):
         blocks, _ = self._row_blocks()
-        out = jax.lax.map(lambda xb: self._gram_fn(xb, self.x) @ v, blocks)
+        out = jax.lax.map(lambda xb: K.f32_dot(self._gram_fn(xb, self.x), v),
+                          blocks)
         return out.reshape(-1)[:self.n]
 
     def full(self):
@@ -393,8 +375,8 @@ class PallasKernelEngine(ChunkedKernelEngine):
         if self._pallas_mode is None:
             return super().matvec(v)
         blocks, _ = self._row_blocks()
-        out = jax.lax.map(lambda xb: self._pallas_gram(xb, self.x) @ v,
-                          blocks)
+        out = jax.lax.map(
+            lambda xb: K.f32_dot(self._pallas_gram(xb, self.x), v), blocks)
         return out.reshape(-1)[:self.n]
 
     def decide(self, z, coef, b=0.0):
@@ -459,7 +441,8 @@ class ShardedKernelEngine(ChunkedKernelEngine):
         v_full = jax.lax.all_gather(v, self.axis, tiled=True)
         blocks, _ = self._row_blocks()
         out = jax.lax.map(
-            lambda xb: self._gram_fn(xb, self.x_full) @ v_full, blocks)
+            lambda xb: K.f32_dot(self._gram_fn(xb, self.x_full), v_full),
+            blocks)
         return out.reshape(-1)[:self.n]
 
     def decide(self, z, coef, b=0.0):

@@ -6,6 +6,13 @@ every Pallas kernel's oracle delegates to the functions here.
 
 All functions take matrices ``A (n, d)`` and ``B (m, d)`` and return the
 Gram block ``K (n, m)`` in float32.
+
+"fp32" means near-f32 products on every backend: a TPU contracts f32
+operands in a single bf16 pass unless told otherwise (about three
+significant digits), so every f32 contraction, here, in the engines and
+in the Pallas kernels, names its precision (``f32_dot``,
+``xla_precision``, ``pallas_precision``). On a CPU XLA computes in f32
+whatever is asked.
 """
 from __future__ import annotations
 
@@ -36,6 +43,30 @@ class KernelParams:
 COMPUTE_DTYPES = ("fp32", "bf16")
 
 
+# f32 contraction precision, as measured on a TPU v5e against the f64
+# KKT certificates and the float64 serving reference (PERF.md): one bf16
+# pass (DEFAULT) fails them, in XLA and in Mosaic alike; three passes
+# (HIGH) hold them in XLA. Mosaic offers only DEFAULT and HIGHEST.
+XLA_F32_PRECISION = jax.lax.Precision.HIGH
+PALLAS_F32_PRECISION = jax.lax.Precision.HIGHEST
+
+
+def xla_precision(dtype):
+    """XLA contraction precision for operands of ``dtype``: three bf16
+    passes for f32, the native single pass for bf16."""
+    return XLA_F32_PRECISION if dtype == jnp.float32 else None
+
+
+def pallas_precision(dtype):
+    """The same for a dot inside a Pallas TPU kernel."""
+    return PALLAS_F32_PRECISION if dtype == jnp.float32 else None
+
+
+def f32_dot(a: jax.Array, b: jax.Array) -> jax.Array:
+    """``a @ b`` of f32 operands in XLA (module docstring)."""
+    return jnp.matmul(a, b, precision=XLA_F32_PRECISION)
+
+
 def _compute_cast(a: jax.Array, b: jax.Array, compute_dtype: str):
     """Round operands to the Gram compute precision. Under "bf16" both
     the dot and the squared norms see the SAME rounded values (the dot
@@ -53,7 +84,8 @@ def _compute_cast(a: jax.Array, b: jax.Array, compute_dtype: str):
 def linear_gram(a: jax.Array, b: jax.Array, *,
                 compute_dtype: str = "fp32") -> jax.Array:
     a, b = _compute_cast(a, b, compute_dtype)
-    return jnp.dot(a, b.T, preferred_element_type=jnp.float32)
+    return jnp.dot(a, b.T, precision=xla_precision(a.dtype),
+                   preferred_element_type=jnp.float32)
 
 
 def poly_gram(a: jax.Array, b: jax.Array, *, gamma: float, degree: int,
@@ -80,7 +112,8 @@ def sqdist(a: jax.Array, b: jax.Array, *,
     bf = b.astype(jnp.float32)
     a2 = jnp.sum(af * af, axis=-1, keepdims=True)        # (n, 1)
     b2 = jnp.sum(bf * bf, axis=-1, keepdims=True).T      # (1, m)
-    d2 = a2 + b2 - 2.0 * jnp.dot(a, b.T, preferred_element_type=jnp.float32)
+    d2 = a2 + b2 - 2.0 * jnp.dot(a, b.T, precision=xla_precision(a.dtype),
+                                 preferred_element_type=jnp.float32)
     return jnp.maximum(d2, 0.0)
 
 

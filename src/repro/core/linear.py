@@ -47,6 +47,8 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from repro.core import kernels as K
+
 
 @dataclasses.dataclass(frozen=True)
 class DCDConfig:
@@ -116,13 +118,13 @@ def dcd_qp(phi: jax.Array, y: jax.Array, p: jax.Array,
         # the f32 drift of n accumulated rank-1 updates to one epoch, so
         # the measured projected gradient IS the certificate quantity
         coef = ys * beta
-        return phi.T @ coef, jnp.sum(coef)
+        return K.f32_dot(phi.T, coef), jnp.sum(coef)
 
     def coord(t, carry):
         beta, w, wb, viol, perm = carry
         i = perm[t]
         phi_i = phi[i]
-        g = y[i] * (phi_i @ w + bias * wb) + p[i]
+        g = y[i] * (K.f32_dot(phi_i, w) + bias * wb) + p[i]
         # projected gradient: the certificate quantity at this coordinate
         at_lo = beta[i] <= lo[i]
         at_hi = beta[i] >= hi[i]

@@ -42,6 +42,8 @@ from typing import NamedTuple, Optional, Sequence
 import numpy as np
 import jax.numpy as jnp
 
+from repro.core import kernels as K
+
 
 # --------------------------------------------------------------------- tasks
 class BinaryTask(NamedTuple):
@@ -217,7 +219,7 @@ def vote_decision(df: jnp.ndarray, pairs: np.ndarray, m: int) -> jnp.ndarray:
     # small integer counts — exact in f32 (the old loop mixed the 1e-6
     # tie term into the same accumulator, where it fell below f32 eps)
     votes = pos.T @ one_pos + (1.0 - pos).T @ one_neg       # (t, m)
-    tie = jnp.tanh(df).T @ (one_pos - one_neg)              # (t, m)
+    tie = K.f32_dot(jnp.tanh(df).T, one_pos - one_neg)      # (t, m)
     # lexicographic argmax: most votes first, largest tie-break margin
     # among the leaders second, lowest class index last (LIBSVM order)
     lead = votes >= jnp.max(votes, axis=1, keepdims=True) - 0.5
@@ -232,7 +234,8 @@ def margin_decision(df: jnp.ndarray, pairs: np.ndarray,
     counts tie."""
     df = jnp.asarray(df, jnp.float32)
     w = jnp.tanh(df)                              # (C, t)
-    score = w.T @ _one_hot(pairs[:, 0], m) - w.T @ _one_hot(pairs[:, 1], m)
+    score = (K.f32_dot(w.T, _one_hot(pairs[:, 0], m))
+             - K.f32_dot(w.T, _one_hot(pairs[:, 1], m)))
     return jnp.argmax(score, axis=1)
 
 

@@ -8,8 +8,7 @@ device". The TPU-native adaptation:
 * the per-sample axis is vectorized (VPU lanes / Pallas VMEM tiles)
   instead of SIMT threads;
 * working-set selection (the block-reduce argmax in CUDA) is a masked
-  max/argmax reduction — optionally the fused Pallas ``kkt_select``
-  kernel;
+  max/argmax reduction on the vector unit;
 * the host-side convergence check becomes the predicate of a
   ``lax.while_loop`` whose body runs ``check_every`` SMO iterations
   (``lax.fori_loop``), mirroring the paper's device-iterations-between-
@@ -79,7 +78,7 @@ from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import kernel_engine as KE
 from repro.core import kernels as K
@@ -131,8 +130,7 @@ def _selection(f, alpha, y, mask, lo, hi):
     """Working-set selection: (b_up, i_up, b_low, i_low).
 
     This is the reduction stage — CUDA block-reduce in the paper, a masked
-    min/argmax on the vector unit here (or the Pallas ``kkt_select``
-    kernel when routed through ``repro.kernels.ops``).
+    min/argmax on the vector unit here.
 
     ``lo`` / ``hi`` are the (broadcastable, possibly per-sample) box
     bounds of the QP spec. Membership epsilon is RELATIVE to the box
@@ -831,9 +829,10 @@ def _sharded_smo_program(mesh: Mesh, axis: str, cfg: SMOConfig,
     on the callable object)."""
     body = partial(_sharded_smo_solve, cfg=cfg, kernel=kernel, ecfg=ecfg)
     spec, rep = P(axis), P()
-    return jax.jit(KE.shard_map_compat(
-        body, mesh, (spec,) * 6,
-        SMOResult(spec, rep, rep, rep, rep, rep)))
+    return jax.jit(jax.shard_map(
+        body, mesh=mesh, in_specs=(spec,) * 6,
+        out_specs=SMOResult(spec, rep, rep, rep, rep, rep),
+        check_vma=False))
 
 
 def _resolve_sharded_cfg(engine, axis: str) -> KE.EngineConfig:
@@ -910,7 +909,11 @@ def sharded_solve_qp(x: jax.Array,
     ecfg = _resolve_sharded_cfg(engine, axis)
     fit = _sharded_smo_program(mesh, axis, cfg, kernel, ecfg)
     r = fit(x, y, p, lo, hi, m)
-    return r._replace(alpha=r.alpha[:n])
+    # the unpadded slice of a sample-sharded array is not a whole number
+    # of shards: ask for it replicated (an Explicit-axis mesh refuses a
+    # plain alpha[:n] here)
+    return r._replace(alpha=r.alpha.at[:n].get(
+        out_sharding=NamedSharding(mesh, P())))
 
 
 def sharded_binary_smo(x: jax.Array,
@@ -981,7 +984,7 @@ def decision_function(x_train, y_train, alpha, b, x_test, *,
     if gram_fn is None:
         gram_fn = K.make_gram_fn(kernel)
     kmat = gram_fn(x_test.astype(jnp.float32), x_train.astype(jnp.float32))
-    return kmat @ coef + b
+    return K.f32_dot(kmat, coef) + b
 
 
 def dual_objective(y, alpha, gram) -> jax.Array:

@@ -547,8 +547,9 @@ class SVC:
             # stacked task_w matrix) — no SV bank, no kernel engine
             phi_t = self._feature_map.transform(xt)
             if self._binary:
-                return np.asarray(phi_t @ jnp.asarray(self.w_) + self.b_)
-            df = phi_t @ jnp.asarray(self.task_w_).T
+                return np.asarray(K.f32_dot(phi_t, jnp.asarray(self.w_))
+                                  + self.b_)
+            df = K.f32_dot(phi_t, jnp.asarray(self.task_w_).T)
             return (np.asarray(df).T
                     + self.task_b_[:, None]).astype(np.float32)
         if self._binary:
@@ -736,7 +737,8 @@ class SVR:
         xt = jnp.asarray(np.asarray(xt, np.float32))
         if self._feature_map is not None:
             phi_t = self._feature_map.transform(xt)
-            return np.asarray(phi_t @ jnp.asarray(self.w_) + self.b_)
+            return np.asarray(K.f32_dot(phi_t, jnp.asarray(self.w_))
+                              + self.b_)
         if self.n_support_ == 0:   # every sample inside the tube
             return np.full(xt.shape[0], self.b_, np.float32)
         eng = KE.make_engine(jnp.asarray(self.support_vectors_),

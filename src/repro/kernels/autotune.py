@@ -1,6 +1,6 @@
 """Roofline-driven tile autotuning for the SVM Pallas kernels.
 
-The hot kernels (``rbf_gram``, ``kkt_select``, ``decision``,
+The hot kernels (``rbf_gram``, ``rff_features``, ``decision``,
 ``multitask_decision``) ship MXU-aligned default tiles that are correct
 everywhere but optimal nowhere in particular. This module makes every
 tile/block knob tunable per (device kind, kernel, dtype, shape bucket):
@@ -11,8 +11,9 @@ tile/block knob tunable per (device kind, kernel, dtype, shape bucket):
   with double buffering (the same structural constraint
   ``tests/test_kernels_pallas.py::test_blockspec_vmem_budget`` pins for
   the defaults);
-* ``roofline_estimate(...)`` prices a configuration with the TPU-v5e
-  roofline constants from ``repro.roofline.collect`` — per-tile HBM
+* ``roofline_estimate(...)`` prices a configuration with the published
+  peaks of one device kind (``repro.roofline.collect.PEAKS``; a kind
+  missing from that table is an error) — per-tile HBM
   traffic (bigger output tiles re-stream fewer operand bytes) vs MXU
   FLOPs, the collect/differential cost model pointed at the SVM kernels
   instead of the transformer stack;
@@ -24,12 +25,13 @@ tile/block knob tunable per (device kind, kernel, dtype, shape bucket):
   never worse than the default under the chosen objective;
 * ``TuningCache`` persists results as versioned JSON keyed by
   ``device|kernel|dtype|bucket``. A missing, corrupted or
-  version-mismatched cache silently falls back to the defaults — tuning
+  version-mismatched cache file falls back to the defaults — tuning
   is an optimization, never a correctness dependency;
 * ``lookup(kernel, shape, dtype)`` is the runtime fast path
   ``kernels.ops`` consults when a caller does not pass explicit block
   sizes: tuned config if the cache has this bucket, ``None`` (-> the
-  hardcoded defaults) otherwise.
+  hardcoded defaults) otherwise. Any other error (no device, say)
+  propagates.
 
 Objectives
 ----------
@@ -42,8 +44,8 @@ Objectives
               and breaks ties with measured wall time.
 
 The cache location is ``$REPRO_TUNE_CACHE`` when set, else
-``~/.cache/repro/autotune.json``; ``repro.roofline.svm_tune`` is the CLI
-driver that fills it.
+``<repo>/.autotune.json`` inside the checkout (gitignored);
+``repro.roofline.svm_tune`` is the CLI driver that fills it.
 """
 from __future__ import annotations
 
@@ -51,10 +53,13 @@ import dataclasses
 import json
 import os
 import time
+from pathlib import Path
 from typing import Callable, Optional
 
 CACHE_VERSION = 1
 _ENV_CACHE = "REPRO_TUNE_CACHE"
+# src/repro/kernels/autotune.py -> the checkout root
+_REPO_ROOT = Path(__file__).resolve().parents[3]
 
 # ~16 MiB/core VMEM; a candidate's double-buffered working set must fit
 VMEM_BUDGET_BYTES = 16 * 2 ** 20
@@ -62,7 +67,6 @@ VMEM_BUDGET_BYTES = 16 * 2 ** 20
 DEFAULTS: dict[str, dict[str, int]] = {
     "rbf_gram": {"block_n": 128, "block_m": 128, "block_d": 128},
     "rff_features": {"block_n": 128, "block_m": 128, "block_d": 128},
-    "kkt_select": {"block": 1024},
     "decision": {"block_t": 128, "block_n": 128},
     "multitask_decision": {"block_t": 128, "block_n": 128},
 }
@@ -76,10 +80,10 @@ _LADDERS: dict[str, dict[str, tuple[int, ...]]] = {
     "rff_features": {"block_n": (64, 128, 256, 512),
                      "block_m": (128, 256, 512),
                      "block_d": (128, 256, 512)},
-    "kkt_select": {"block": (256, 512, 1024, 2048, 4096)},
     "decision": {"block_t": (64, 128, 256, 512),
                  "block_n": (128, 256, 512, 1024)},
-    "multitask_decision": {"block_t": (64, 128, 256, 512),
+    # block_t is the lane axis of this kernel's output block: >= 128
+    "multitask_decision": {"block_t": (128, 256, 512),
                            "block_n": (128, 256, 512, 1024)},
 }
 
@@ -102,7 +106,6 @@ def shape_bucket(kernel: str, shape: tuple[int, ...]) -> str:
     axes = {
         "rbf_gram": ("n", "m", "d"),
         "rff_features": ("n", "k", "d"),
-        "kkt_select": ("n",),
         "decision": ("t", "n", "d"),
         "multitask_decision": ("tasks", "t", "w", "d"),
     }[kernel]
@@ -129,9 +132,6 @@ def _block_dims(kernel: str, shape: tuple[int, ...]) -> dict[str, int]:
     if kernel in ("rbf_gram", "rff_features"):
         n, m, d = shape
         return {"block_n": n, "block_m": m, "block_d": d}
-    if kernel == "kkt_select":
-        n, = shape
-        return {"block": n}
     if kernel == "decision":
         t, n, _ = shape
         return {"block_t": t, "block_n": n}
@@ -152,8 +152,6 @@ def _vmem_bytes(kernel: str, cfg: dict, shape: tuple[int, ...],
     if kernel == "rff_features":
         bn, bm, bd = cfg["block_n"], cfg["block_m"], cfg["block_d"]
         return (bn * bd + bd * bm) * es + (bn * bm + bm) * 4
-    if kernel == "kkt_select":
-        return 4 * cfg["block"] * 4
     d = shape[-1]
     bt, bn = cfg["block_t"], cfg["block_n"]
     return (bt * d + bn * d) * es + (bn + bt) * 4
@@ -205,8 +203,10 @@ def clip_to_candidates(kernel: str, cfg: dict[str, int],
 
 # ------------------------------------------------------ roofline pricing
 def roofline_estimate(kernel: str, shape: tuple[int, ...],
-                      dtype: str, cfg: dict[str, int]) -> dict:
-    """Analytic per-call roofline terms for one tile configuration.
+                      dtype: str, cfg: dict[str, int], *,
+                      device_kind: str) -> dict:
+    """Analytic per-call roofline terms for one tile configuration on
+    ``device_kind``.
 
     HBM traffic follows the kernels' actual pipelining: an operand tile
     is re-fetched whenever its block index changes along the grid
@@ -231,10 +231,6 @@ def roofline_estimate(kernel: str, shape: tuple[int, ...],
                + _ceil_div(n, bn) * k * d * es    # Omega re-streamed per i
                + n * k * 4                        # features written once
                + _ceil_div(n, bn) * k * 4)        # phase per i
-    elif kernel == "kkt_select":
-        n, = shape
-        flops = 12.0 * n
-        hbm = 4 * n * 4 + 4 * _ceil_div(n, cfg["block"]) * 4
     elif kernel == "decision":
         t, n, d = shape
         bt = cfg["block_t"]
@@ -253,7 +249,8 @@ def roofline_estimate(kernel: str, shape: tuple[int, ...],
         raise ValueError(f"unknown tunable kernel {kernel!r}")
     from repro.roofline.collect import roofline_terms
     terms = roofline_terms(flops=flops, hbm_bytes=hbm,
-                           collective_bytes_total=0.0)
+                           collective_bytes_total=0.0,
+                           device_kind=device_kind)
     terms["flops"] = flops
     terms["hbm_bytes"] = hbm
     return terms
@@ -297,15 +294,6 @@ def _bench_closure(kernel: str, shape: tuple[int, ...], dtype: str,
         scale = float(np.sqrt(2.0 / k))
         return lambda: ops.rff_features(x, omega, phase, scale=scale,
                                         compute_dtype=dtype, **cfg)
-    if kernel == "kkt_select":
-        n, = shape
-        f = jnp.asarray(rng.normal(size=n).astype(np.float32))
-        alpha = jnp.asarray(rng.uniform(0, 1, size=n).astype(np.float32))
-        y = jnp.asarray(np.where(rng.random(n) < 0.5, 1.0, -1.0)
-                        .astype(np.float32))
-        mask = jnp.ones(n, bool)
-        return lambda: ops.kkt_select(f, alpha, y, mask, c=1.0,
-                                      block=cfg["block"])
     if kernel == "decision":
         t, n, d = shape
         xt = jnp.asarray(rng.normal(size=(t, d)).astype(np.float32))
@@ -382,6 +370,10 @@ def tune(kernel: str, shape: tuple[int, ...], *, dtype: str = "fp32",
          warmup: int = 1, iters: int = 3) -> TuneResult:
     """Hillclimb the tile configuration for one (kernel, shape, dtype).
 
+    The roofline terms price the configuration on the attached device,
+    which is an error where that device has no entry in
+    ``repro.roofline.collect.PEAKS`` (a CPU).
+
     Starts from the (shape-clipped) default, evaluates its single-axis
     x2 / /2 neighbours, moves to the strict best, and repeats until no
     neighbour improves or ``budget`` configurations have been evaluated.
@@ -389,6 +381,7 @@ def tune(kernel: str, shape: tuple[int, ...], *, dtype: str = "fp32",
     worse than the default under the chosen objective.
     """
     obj = _resolve_objective(objective)
+    kind = device_kind()
     space = candidates(kernel, shape, dtype)
     measure_wall = obj in ("wall", "combined")
 
@@ -401,8 +394,9 @@ def tune(kernel: str, shape: tuple[int, ...], *, dtype: str = "fp32",
         k = key(cfg)
         if k in evaluated:
             return evaluated[k]
-        roofline_s = roofline_estimate(kernel, shape, dtype,
-                                       cfg)["t_total_est_s"]
+        roofline_s = roofline_estimate(
+            kernel, shape, dtype, cfg,
+            device_kind=kind)["t_total_est_s"]
         wall = (_timeit(_bench_closure(kernel, shape, dtype, cfg),
                         warmup=warmup, iters=iters)
                 if measure_wall else None)
@@ -432,11 +426,9 @@ def tune(kernel: str, shape: tuple[int, ...], *, dtype: str = "fp32",
 
 # ----------------------------------------------------------- disk cache
 def default_cache_path() -> str:
-    env = os.environ.get(_ENV_CACHE)
-    if env:
-        return env
-    return os.path.join(os.path.expanduser("~"), ".cache", "repro",
-                        "autotune.json")
+    """``$REPRO_TUNE_CACHE`` when set, else a file inside the checkout:
+    nothing outside it is read unless a caller names it."""
+    return os.environ.get(_ENV_CACHE) or str(_REPO_ROOT / ".autotune.json")
 
 
 class TuningCache:
@@ -531,15 +523,13 @@ def _runtime(path: Optional[str] = None) -> TuningCache:
 def lookup(kernel: str, shape: tuple[int, ...],
            dtype: str = "fp32") -> Optional[dict[str, int]]:
     """Tuned config for this (device, kernel, dtype, shape bucket) or
-    ``None`` when untuned (callers then use ``DEFAULTS``). Total
-    fallback safety: any error here means "no tuned config"."""
-    try:
-        cache = _runtime()
-        if not cache.entries:
-            return None
-        return cache.get(cache_key(device_kind(), kernel, dtype, shape))
-    except Exception:
+    ``None`` when untuned (callers then use ``DEFAULTS``). A missing or
+    unreadable cache file is "untuned" (``TuningCache.load``); any other
+    error propagates."""
+    cache = _runtime()
+    if not cache.entries:
         return None
+    return cache.get(cache_key(device_kind(), kernel, dtype, shape))
 
 
 def resolve_blocks(kernel: str, shape: tuple[int, ...], dtype: str,

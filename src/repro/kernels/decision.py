@@ -27,8 +27,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.rbf_gram import (_COMPUTE_DTYPES,
-                                    check_block_divisibility)
+from repro.core.kernels import pallas_precision
+from repro.kernels.rbf_gram import _COMPUTE_DTYPES, check_block_divisibility
 
 
 def _decision_kernel(xt_ref, xr_ref, coef_ref, out_ref, *,
@@ -47,6 +47,7 @@ def _decision_kernel(xt_ref, xr_ref, coef_ref, out_ref, *,
     # f32 accumulation; norms use f32 of the SAME rounded values so the
     # zero-distance diagonal stays exact under mixed precision
     dot = jax.lax.dot_general(xt, xr, (((1,), (1,)), ((), ())),
+                              precision=pallas_precision(xt.dtype),
                               preferred_element_type=jnp.float32)
     xtf = xt.astype(jnp.float32)
     xrf = xr.astype(jnp.float32)
@@ -58,7 +59,7 @@ def _decision_kernel(xt_ref, xr_ref, coef_ref, out_ref, *,
 
 def decision_pallas(x_test: jax.Array, x_train: jax.Array, coef: jax.Array,
                     *, gamma: float, block_t: int = 128, block_n: int = 128,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool) -> jax.Array:
     """Returns (nt,) decision values WITHOUT bias (add b outside).
 
     Shapes must be pre-padded: nt % block_t == 0, n % block_n == 0;
@@ -102,10 +103,11 @@ def _multitask_kernel(xt_ref, sv_ref, coef_ref, out_ref, *,
         out_ref[...] = jnp.zeros_like(out_ref)
 
     xt = xt_ref[...]                              # (bt, d) f32 or bf16
-    sv = sv_ref[...][0]                           # (bn, d) task-t SV tile
-    coef = coef_ref[...].astype(jnp.float32)      # (1, bn)
+    sv = sv_ref[0]                                # (bn, d) task-t SV tile
+    coef = coef_ref[0].astype(jnp.float32)        # (1, bn)
 
     dot = jax.lax.dot_general(xt, sv, (((1,), (1,)), ((), ())),
+                              precision=pallas_precision(xt.dtype),
                               preferred_element_type=jnp.float32)
     if mode == "rbf":
         xtf = xt.astype(jnp.float32)
@@ -115,14 +117,14 @@ def _multitask_kernel(xt_ref, sv_ref, coef_ref, out_ref, *,
         kblock = jnp.exp(-gamma * jnp.maximum(t2 + r2 - 2.0 * dot, 0.0))
     else:                                         # linear
         kblock = dot
-    out_ref[...] += jnp.sum(kblock * coef, axis=1, keepdims=True).T
+    out_ref[0] += jnp.sum(kblock * coef, axis=1, keepdims=True).T
 
 
 def multitask_decision_pallas(x_test: jax.Array, sv_x: jax.Array,
                               coef: jax.Array, *, gamma: float,
                               mode: str = "rbf", block_t: int = 128,
                               block_n: int = 128,
-                              interpret: bool = True) -> jax.Array:
+                              interpret: bool) -> jax.Array:
     """(T, nt) stacked decision values WITHOUT bias (add b outside).
 
     ``sv_x`` is a (T, w, d) serving bucket: T binary tasks padded to a
@@ -137,6 +139,10 @@ def multitask_decision_pallas(x_test: jax.Array, sv_x: jax.Array,
                          f"differ ({d} vs {d2})")
     check_block_divisibility("multitask_decision_pallas",
                              nt=(nt, block_t), w=(w, block_n))
+    if block_t % 128:
+        raise ValueError(f"multitask_decision_pallas: block_t={block_t} "
+                         f"must be a multiple of 128 (it is the lane "
+                         f"axis of the output block)")
     if coef.shape != (n_tasks, w):
         raise ValueError(f"multitask_decision_pallas: coef shape "
                          f"{coef.shape} != bank shape {(n_tasks, w)}")
@@ -146,15 +152,19 @@ def multitask_decision_pallas(x_test: jax.Array, sv_x: jax.Array,
         sv_x = sv_x.astype(jnp.float32)
     grid = (n_tasks, nt // block_t, w // block_n)
     kernel = functools.partial(_multitask_kernel, gamma=gamma, mode=mode)
-    return pl.pallas_call(
+    # coef and the output carry a unit middle axis so the last two block
+    # dims are (1 == full extent, lane multiple) — the TPU tiling rule a
+    # (1, block) block over a (T, w) array breaks for T > 1
+    out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_t, d), lambda t, i, k: (i, 0)),
             pl.BlockSpec((1, block_n, d), lambda t, i, k: (t, k, 0)),
-            pl.BlockSpec((1, block_n), lambda t, i, k: (t, k)),
+            pl.BlockSpec((1, 1, block_n), lambda t, i, k: (t, 0, k)),
         ],
-        out_specs=pl.BlockSpec((1, block_t), lambda t, i, k: (t, i)),
-        out_shape=jax.ShapeDtypeStruct((n_tasks, nt), jnp.float32),
+        out_specs=pl.BlockSpec((1, 1, block_t), lambda t, i, k: (t, 0, i)),
+        out_shape=jax.ShapeDtypeStruct((n_tasks, 1, nt), jnp.float32),
         interpret=interpret,
-    )(x_test, sv_x, coef)
+    )(x_test, sv_x, coef.reshape(n_tasks, 1, w))
+    return out[:, 0, :]

@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core.kernels import pallas_precision
 from repro.kernels.rbf_gram import check_block_divisibility
 
 _COMPUTE_DTYPES = (jnp.float32, jnp.bfloat16)
@@ -49,6 +50,7 @@ def _rff_kernel(x_ref, w_ref, ph_ref, out_ref, *, scale: float,
     w = w_ref[...]                               # (bd, bm)
     out_ref[...] += jax.lax.dot_general(
         x, w, (((1,), (0,)), ((), ())),           # x @ w on the MXU
+        precision=pallas_precision(x.dtype),
         preferred_element_type=jnp.float32)
 
     @pl.when(k == n_d_steps - 1)
@@ -59,7 +61,7 @@ def _rff_kernel(x_ref, w_ref, ph_ref, out_ref, *, scale: float,
 def rff_features_pallas(x: jax.Array, omega: jax.Array, phase: jax.Array,
                         *, scale: float, block_n: int = 128,
                         block_m: int = 128, block_d: int = 128,
-                        interpret: bool = True) -> jax.Array:
+                        interpret: bool) -> jax.Array:
     """Feature block ``scale * cos(x @ omega + phase)`` of shape (n, k).
 
     ``x (n, d)``, ``omega (d, k)``, ``phase (1, k)`` must be pre-padded

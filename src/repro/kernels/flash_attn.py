@@ -82,7 +82,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 def flash_attention_pallas(q, k, v, *, causal: bool = True,
                            block_q: int = 256, block_k: int = 256,
-                           interpret: bool = True, kv_len: int = 0):
+                           interpret: bool, kv_len: int = 0):
     """q (BH, Sq, d), k/v (BH, Sk, d) -> (BH, Sq, d).
 
     Batch and heads pre-flattened (GQA head-broadcast handled by the

@@ -6,8 +6,10 @@ These are the entry points the rest of the framework uses. They
 (device, kernel, dtype, shape bucket) and the hardcoded defaults are
 the fallback; (2) pad every axis up to the kernel's block multiples
 (MXU/VMEM alignment); (3) dispatch the pallas_call; (4) slice the
-padding back off. ``interpret`` defaults to auto: True off-TPU (this
-container), False on real TPU hardware.
+padding back off. Whether a kernel compiles for the device or runs in
+the Pallas interpreter is decided here and nowhere else (``on_tpu``):
+compiled on a TPU, interpreted on every other backend (the CPU test
+suite). The raw ``*_pallas`` kernels take ``interpret`` with no default.
 
 Mixed precision: the Gram-shaped kernels take ``compute_dtype``
 ("fp32" | "bf16"). Under "bf16" the operand tiles are cast to bfloat16
@@ -21,7 +23,6 @@ Padding correctness notes:
   to the dot and to the squared norms; padded SAMPLE rows produce extra
   rows/cols that are sliced off.
 * decision: padded train rows carry coef = 0 -> contribute 0.
-* kkt_select: padded entries get mask = False -> +-inf sentinels.
 """
 from __future__ import annotations
 
@@ -32,14 +33,15 @@ import jax.numpy as jnp
 
 from repro.kernels import autotune
 from repro.kernels import decision as _decision
-from repro.kernels import kkt_select as _kkt
 from repro.kernels import rbf_gram as _gram
 
 COMPUTE_DTYPES = ("fp32", "bf16")
 
 
-def _auto_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def on_tpu() -> bool:
+    """True when the default backend is a TPU: the kernels compile for
+    it. Elsewhere they run in the Pallas interpreter (exact, slow)."""
+    return jax.default_backend() == "tpu"
 
 
 def _check_compute_dtype(compute_dtype: str) -> None:
@@ -83,19 +85,16 @@ def _rbf_gram_padded(a, b, *, gamma, mode, block_n, block_m, block_d,
 def rbf_gram(a: jax.Array, b: jax.Array, *, gamma: float = 1.0,
              mode: str = "rbf", block_n: int | None = None,
              block_m: int | None = None, block_d: int | None = None,
-             compute_dtype: str = "fp32",
-             interpret: bool | None = None) -> jax.Array:
+             compute_dtype: str = "fp32") -> jax.Array:
     """K(a, b): (n, m) float32 Gram matrix (rbf or linear). Block sizes
     left as ``None`` resolve through the autotune cache."""
     _check_compute_dtype(compute_dtype)
-    if interpret is None:
-        interpret = _auto_interpret()
     blocks = autotune.resolve_blocks(
         "rbf_gram", (a.shape[0], b.shape[0], a.shape[1]), compute_dtype,
         {"block_n": block_n, "block_m": block_m, "block_d": block_d})
     return _rbf_gram_padded(a, b, gamma=gamma, mode=mode,
                             compute_dtype=compute_dtype,
-                            interpret=interpret, **blocks)
+                            interpret=not on_tpu(), **blocks)
 
 
 # ----------------------------------------------------------- rff_features
@@ -121,52 +120,18 @@ def _rff_features_padded(x, omega, phase, *, scale, block_n, block_m,
 def rff_features(x: jax.Array, omega: jax.Array, phase: jax.Array, *,
                  scale: float, block_n: int | None = None,
                  block_m: int | None = None, block_d: int | None = None,
-                 compute_dtype: str = "fp32",
-                 interpret: bool | None = None) -> jax.Array:
+                 compute_dtype: str = "fp32") -> jax.Array:
     """Fused RFF transform ``scale * cos(x @ omega + phase)``: (n, k)
     float32 feature block (``repro.core.approx.RFFMap``'s TPU path).
     Block sizes left as ``None`` resolve through the autotune cache."""
     _check_compute_dtype(compute_dtype)
-    if interpret is None:
-        interpret = _auto_interpret()
     blocks = autotune.resolve_blocks(
         "rff_features", (x.shape[0], omega.shape[1], x.shape[1]),
         compute_dtype,
         {"block_n": block_n, "block_m": block_m, "block_d": block_d})
     return _rff_features_padded(x, omega, phase, scale=float(scale),
                                 compute_dtype=compute_dtype,
-                                interpret=interpret, **blocks)
-
-
-# ------------------------------------------------------------- kkt_select
-@partial(jax.jit, static_argnames=("c", "block", "interpret"))
-def _kkt_select_padded(f, alpha, y, mask, *, c, block, interpret):
-    fp = _pad_to(f.astype(jnp.float32), 0, block)
-    ap = _pad_to(alpha.astype(jnp.float32), 0, block)
-    # padded y = +1 with alpha = 0 would look movable; mask handles it
-    yp = _pad_to(y.astype(jnp.float32), 0, block)
-    mp = _pad_to(mask.astype(jnp.int32), 0, block)
-    upv, upi, lowv, lowi = _kkt.kkt_select_pallas(fp, ap, yp, mp, c=c,
-                                                  block=block,
-                                                  interpret=interpret)
-    t_up = jnp.argmin(upv)
-    t_low = jnp.argmax(lowv)
-    return upv[t_up], upi[t_up], lowv[t_low], lowi[t_low]
-
-
-def kkt_select(f: jax.Array, alpha: jax.Array, y: jax.Array,
-               mask: jax.Array, *, c: float = 1.0,
-               block: int | None = None,
-               interpret: bool | None = None):
-    """Fused masked KKT selection: (b_up, i_up, b_low, i_low)."""
-    if interpret is None:
-        interpret = _auto_interpret()
-    n = f.shape[0]
-    block = autotune.resolve_blocks("kkt_select", (n,), "fp32",
-                                    {"block": block})["block"]
-    block = min(block, max(128, 1 << (n - 1).bit_length()))
-    return _kkt_select_padded(f, alpha, y, mask, c=c, block=block,
-                              interpret=interpret)
+                                interpret=not on_tpu(), **blocks)
 
 
 # --------------------------------------------------------------- decision
@@ -190,18 +155,15 @@ def _decision_padded(x_test, x_train, coef, b, *, gamma, block_t, block_n,
 def decision(x_test: jax.Array, x_train: jax.Array, coef: jax.Array,
              b: jax.Array | float = 0.0, *, gamma: float = 1.0,
              block_t: int | None = None, block_n: int | None = None,
-             compute_dtype: str = "fp32",
-             interpret: bool | None = None) -> jax.Array:
+             compute_dtype: str = "fp32") -> jax.Array:
     """f(z) = K(z, X) @ coef + b for a batch of test rows."""
     _check_compute_dtype(compute_dtype)
-    if interpret is None:
-        interpret = _auto_interpret()
     blocks = autotune.resolve_blocks(
         "decision", (x_test.shape[0], x_train.shape[0], x_test.shape[1]),
         compute_dtype, {"block_t": block_t, "block_n": block_n})
     return _decision_padded(x_test, x_train, coef, b, gamma=gamma,
                             compute_dtype=compute_dtype,
-                            interpret=interpret, **blocks)
+                            interpret=not on_tpu(), **blocks)
 
 
 # ----------------------------------------------------- multitask_decision
@@ -226,8 +188,7 @@ def multitask_decision(x_test: jax.Array, sv_x: jax.Array, coef: jax.Array,
                        b: jax.Array | None = None, *, gamma: float = 1.0,
                        mode: str = "rbf", block_t: int | None = None,
                        block_n: int | None = None,
-                       compute_dtype: str = "fp32",
-                       interpret: bool | None = None) -> jax.Array:
+                       compute_dtype: str = "fp32") -> jax.Array:
     """f_t(z) = K(z, SV_t) @ coef_t + b_t for a stacked (T, w, d) SV bank.
 
     One fused grid over every task of a serving bucket (the batched
@@ -239,8 +200,6 @@ def multitask_decision(x_test: jax.Array, sv_x: jax.Array, coef: jax.Array,
         raise ValueError(f"unknown multitask decision mode {mode!r}; "
                          "expected 'rbf' or 'linear'")
     _check_compute_dtype(compute_dtype)
-    if interpret is None:
-        interpret = _auto_interpret()
     nt = x_test.shape[0]
     n_tasks, w, _ = sv_x.shape
     if w == 0:  # no support vectors anywhere: constant-bias predictor
@@ -252,22 +211,18 @@ def multitask_decision(x_test: jax.Array, sv_x: jax.Array, coef: jax.Array,
     return _multitask_decision_padded(x_test, sv_x, coef, b, gamma=gamma,
                                       mode=mode,
                                       compute_dtype=compute_dtype,
-                                      interpret=interpret, **blocks)
+                                      interpret=not on_tpu(), **blocks)
 
 
-@partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
-                                   "interpret"))
+@partial(jax.jit, static_argnames=("causal", "block_q", "block_k"))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, block_q: int = 256,
-                    block_k: int = 256,
-                    interpret: bool | None = None) -> jax.Array:
+                    block_k: int = 256) -> jax.Array:
     """Flash attention over (B, S, H, D) tensors with GQA broadcast.
 
     Pads S to tile multiples (padded KV masked out via causality for
     causal=True; for the padded q rows the outputs are sliced off)."""
     from repro.kernels import flash_attn as _fa
-    if interpret is None:
-        interpret = _auto_interpret()
     b, sq, h, d = q.shape
     hkv = k.shape[2]
     if hkv != h:  # GQA: broadcast kv heads to q heads
@@ -284,15 +239,14 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                                           vp.shape[3])
     out = _fa.flash_attention_pallas(qf, kf, vf, causal=causal,
                                      block_q=bq, block_k=bk,
-                                     interpret=interpret,
+                                     interpret=not on_tpu(),
                                      kv_len=k.shape[1])
     out = out.reshape(b, h, qp.shape[1], vp.shape[3]).transpose(0, 2, 1, 3)
     return out[:, :sq]
 
 
 def gram_row_fn(*, gamma: float, block: int | None = None,
-                mode: str = "rbf", compute_dtype: str = "fp32",
-                interpret: bool | None = None):
+                mode: str = "rbf", compute_dtype: str = "fp32"):
     """``(X, z) -> K(X, z)`` single-row closure for the SMO f-cache update
     (the on-the-fly, O(n d)-memory mode used by the chunked/Pallas
     ``KernelEngine`` backends; ``mode``/``compute_dtype`` mirror
@@ -300,6 +254,5 @@ def gram_row_fn(*, gamma: float, block: int | None = None,
     def row(x, z):
         return rbf_gram(x, z[None, :], gamma=gamma, mode=mode,
                         block_n=block, block_m=128,
-                        compute_dtype=compute_dtype,
-                        interpret=interpret)[:, 0]
+                        compute_dtype=compute_dtype)[:, 0]
     return row

@@ -41,6 +41,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core.kernels import pallas_precision
+
 _COMPUTE_DTYPES = (jnp.float32, jnp.bfloat16)
 
 
@@ -78,6 +80,7 @@ def _rbf_gram_kernel(a_ref, b_ref, a2_ref, b2_ref, out_ref, *,
     b = b_ref[...]                              # (bm, bd)
     out_ref[...] += jax.lax.dot_general(
         a, b, (((1,), (1,)), ((), ())),          # a @ b.T on the MXU
+        precision=pallas_precision(a.dtype),
         preferred_element_type=jnp.float32)
 
     @pl.when(k == n_d_steps - 1)
@@ -91,7 +94,7 @@ def _rbf_gram_kernel(a_ref, b_ref, a2_ref, b2_ref, out_ref, *,
 def rbf_gram_pallas(a: jax.Array, b: jax.Array, *, gamma: float,
                     block_n: int = 128, block_m: int = 128,
                     block_d: int = 128, mode: str = "rbf",
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool) -> jax.Array:
     """Gram block K(a, b) of shape (n, m). Inputs must be pre-padded to
     multiples of the block sizes (see ``ops.rbf_gram`` for the public,
     padding-aware wrapper). bf16 inputs run the mixed-precision path:

@@ -21,25 +21,6 @@ def linear_gram(a: jax.Array, b: jax.Array) -> jax.Array:
     return K.linear_gram(a, b)
 
 
-def kkt_select(f: jax.Array, alpha: jax.Array, y: jax.Array,
-               mask: jax.Array, c: float):
-    """(b_up, i_up, b_low, i_low) — masked KKT min/argmin & max/argmax.
-
-    Same semantics as ``repro.core.smo._selection``.
-    """
-    eps = 1e-6 * c
-    pos, neg = y > 0, y <= 0
-    not_upper = alpha < c - eps
-    not_lower = alpha > eps
-    up_mask = mask & ((pos & not_upper) | (neg & not_lower))
-    low_mask = mask & ((pos & not_lower) | (neg & not_upper))
-    f_up = jnp.where(up_mask, f, jnp.inf)
-    f_low = jnp.where(low_mask, f, -jnp.inf)
-    i_up = jnp.argmin(f_up)
-    i_low = jnp.argmax(f_low)
-    return f_up[i_up], i_up, f_low[i_low], i_low
-
-
 def decision(x_test: jax.Array, x_train: jax.Array, coef: jax.Array,
              b: jax.Array, gamma: float) -> jax.Array:
     """f(z) = sum_i coef_i exp(-gamma||x_i - z||^2) + b, coef = alpha*y."""

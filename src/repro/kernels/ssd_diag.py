@@ -48,7 +48,7 @@ def _ssd_diag_kernel(c_ref, b_ref, x_ref, dt_ref, cs_ref, o_ref):
         preferred_element_type=jnp.float32).astype(o_ref.dtype)
 
 
-def ssd_diag_pallas(cmat, bmat, x, dt, cs, *, interpret: bool = True):
+def ssd_diag_pallas(cmat, bmat, x, dt, cs, *, interpret: bool):
     """Intra-chunk SSD contribution.
 
     cmat/bmat (BC, Q, N)  — chunk C/B projections (group-shared, G=1)

@@ -35,7 +35,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import (ARCH_NAMES, INPUT_SHAPES, get_config,
                                 supports_shape)
-from repro.launch.mesh import make_production_mesh, set_mesh
+from repro.launch.mesh import make_production_mesh
 from repro.launch import specs as SP
 from repro.models.model import Model, abstract_init
 from repro.optim.adamw import AdamW
@@ -124,7 +124,7 @@ def lower_combo(arch: str, shape_name: str, *, multi_pod: bool,
             in_shardings=(p_shardings, o_shardings, b_shardings),
             out_shardings=(p_shardings, o_shardings,
                            NamedSharding(mesh, P())))
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jitted.lower(params_shapes, opt_shapes, bspecs)
     elif shape.kind == "prefill":
         bspecs, bshard = SP.batch_specs(cfg, shape, mesh)
@@ -139,7 +139,7 @@ def lower_combo(arch: str, shape_name: str, *, multi_pod: bool,
             model.prefill,
             in_shardings=(p_shardings, b_shardings, c_shardings),
             out_shardings=(NamedSharding(mesh, P()), c_shardings))
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jitted.lower(params_shapes, bspecs, cache_shapes)
     else:  # decode
         tok_spec, tok_ps = SP.decode_token_specs(cfg, shape, mesh)
@@ -155,7 +155,7 @@ def lower_combo(arch: str, shape_name: str, *, multi_pod: bool,
             in_shardings=(p_shardings, NamedSharding(mesh, tok_ps),
                           c_shardings),
             out_shardings=(logits_sh, c_shardings))
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jitted.lower(params_shapes, tok_spec, cache_shapes)
 
     t_lower = time.time() - t0

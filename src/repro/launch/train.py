@@ -47,6 +47,7 @@ def main(argv=None):
 
     from repro.configs.base import get_config, reduced as make_reduced
     from repro.data.lm import token_batches
+    from repro.launch.mesh import make_mesh
     from repro.models.model import Model, abstract_init
     from repro.optim.adamw import AdamW, cosine_schedule
     from repro.sharding import rules
@@ -70,7 +71,7 @@ def main(argv=None):
     mesh = None
     if args.mesh:
         d, m = (int(v) for v in args.mesh.split("x"))
-        mesh = jax.make_mesh((d, m), ("data", "model"))
+        mesh = make_mesh((d, m), ("data", "model"))
         shardings = jax.tree.map(
             lambda lg: NamedSharding(mesh, rules.spec(lg, mesh)),
             logical, is_leaf=lambda x: isinstance(x, tuple))
@@ -92,8 +93,7 @@ def main(argv=None):
     t0 = time.time()
     it = token_batches(vocab_size=cfg.vocab_size, batch=args.batch,
                        seq_len=args.seq, n_batches=args.steps, seed=1)
-    from repro.launch.mesh import set_mesh
-    ctx = set_mesh(mesh) if mesh is not None else None
+    ctx = jax.set_mesh(mesh) if mesh is not None else None
     if ctx:
         ctx.__enter__()
     try:

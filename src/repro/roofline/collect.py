@@ -4,17 +4,35 @@
   bytes of every all-gather / all-reduce / reduce-scatter / all-to-all /
   collective-permute (cost_analysis does not report collectives).
 * ``roofline_terms`` converts (cost, memory, collectives) into the three
-  per-device time terms against TPU v5e constants.
+  per-device time terms against the published peaks of one device kind
+  (``PEAKS``, keyed by ``jax.Device.device_kind``).
 """
 from __future__ import annotations
 
 import re
 from typing import Optional
 
-# TPU v5e, per chip
-PEAK_FLOPS_BF16 = 197e12       # FLOP/s
-HBM_BW = 819e9                 # B/s
-ICI_BW_PER_LINK = 50e9         # B/s (per direction per link)
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+# Source: Google Cloud TPU documentation, "TPU v5e" (system
+# architecture): 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s
+# of inter-chip interconnect (4 links, 50 GB/s each per direction).
+V5E = "TPU v5 lite"
+PEAKS = {
+    V5E: {"flops_bf16": 197e12, "hbm_bytes_per_s": 819e9,
+          "ici_bytes_per_s_per_link": 50e9},
+}
+
+
+def peaks(device_kind: str) -> dict:
+    """Published peaks of ``device_kind``; a kind missing from ``PEAKS``
+    is an error, never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; known "
+            f"kinds: {sorted(PEAKS)}") from None
+
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -70,17 +88,19 @@ def summarize_cost(cost: dict) -> dict:
 
 
 def roofline_terms(*, flops: float, hbm_bytes: float,
-                   collective_bytes_total: float,
+                   collective_bytes_total: float, device_kind: str,
                    ici_links: int = 4) -> dict:
-    """Per-device seconds for each roofline term.
+    """Per-device seconds for each roofline term on ``device_kind``.
 
     collective traffic is divided by the per-chip aggregate ICI bandwidth
     (links x per-link BW) — optimistic ring assumption, consistent across
     configs so RELATIVE comparisons hold.
     """
-    t_compute = flops / PEAK_FLOPS_BF16
-    t_memory = hbm_bytes / HBM_BW
-    t_coll = collective_bytes_total / (ici_links * ICI_BW_PER_LINK)
+    pk = peaks(device_kind)
+    t_compute = flops / pk["flops_bf16"]
+    t_memory = hbm_bytes / pk["hbm_bytes_per_s"]
+    t_coll = collective_bytes_total / (ici_links
+                                       * pk["ici_bytes_per_s_per_link"])
     dom = max((t_compute, "compute"), (t_memory, "memory"),
               (t_coll, "collective"))
     return {"t_compute_s": t_compute, "t_memory_s": t_memory,

@@ -81,7 +81,7 @@ def run(arch: str, shape: str, variant: str, *, multi_pod: bool = False,
     RT.set_flags(**VARIANTS[variant])
 
     from repro.roofline.differential import probe
-    from repro.roofline.collect import roofline_terms
+    from repro.roofline.collect import V5E, roofline_terms
 
     res = probe(arch, shape, multi_pod=multi_pod)
     if res["status"] != "ok":
@@ -95,7 +95,8 @@ def run(arch: str, shape: str, variant: str, *, multi_pod: bool = False,
         c = {k: v * m for k, v in c.items()}
         res["corrected"] = c
     terms = roofline_terms(flops=c["flops"], hbm_bytes=c["bytes_accessed"],
-                           collective_bytes_total=c["collective_total"])
+                           collective_bytes_total=c["collective_total"],
+                           device_kind=V5E)
 
     full_mem = None
     if not skip_full:

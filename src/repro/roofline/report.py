@@ -13,7 +13,7 @@ import json
 import sys
 
 from repro.configs.base import INPUT_SHAPES, get_config
-from repro.roofline.collect import model_flops, roofline_terms
+from repro.roofline.collect import V5E, model_flops, roofline_terms
 
 
 def load(paths) -> list[dict]:
@@ -47,7 +47,7 @@ def analyze(row: dict, diff: dict | None = None) -> dict | None:
         hbm = row["cost"]["bytes_accessed"]           # per device
         coll = row["collectives"]["total_bytes"]      # per device
     terms = roofline_terms(flops=flops, hbm_bytes=hbm,
-                           collective_bytes_total=coll)
+                           collective_bytes_total=coll, device_kind=V5E)
     if shape.kind == "train":
         tokens = shape.global_batch * shape.seq_len
         mf = model_flops(cfg.param_count(), cfg.active_param_count(),

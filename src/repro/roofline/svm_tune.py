@@ -3,7 +3,7 @@ SVM hot loops instead of the transformer stack.
 
     PYTHONPATH=src python -m repro.roofline.svm_tune \
         --kernel rbf_gram --shape 1024x1024x128 \
-        --dtype fp32 --budget 12 --out ~/.cache/repro/autotune.json
+        --dtype fp32 --budget 12 --out .autotune.json
 
     # tune every SVM kernel at its default shape sweep:
     PYTHONPATH=src python -m repro.roofline.svm_tune --kernel all
@@ -19,7 +19,6 @@ runtime. Existing cache entries for other keys are preserved.
 Shapes are 'x'-separated per kernel:
     rbf_gram            NxMxD        (Gram block)
     rff_features        NxKxD        (samples x random features x dims)
-    kkt_select          N            (sample count)
     decision            TxNxD        (test batch x train rows x features)
     multitask_decision  TASKSxTxWxD  (serving bucket)
 """
@@ -30,15 +29,14 @@ import sys
 DEFAULT_SHAPES = {
     "rbf_gram": ["1024x1024x128", "4096x4096x128"],
     "rff_features": ["16384x256x128"],
-    "kkt_select": ["4096", "16384"],
     "decision": ["256x2048x128"],
     "multitask_decision": ["8x256x512x128"],
 }
 
 
 def parse_shape(kernel: str, text: str) -> tuple:
-    arity = {"rbf_gram": 3, "rff_features": 3, "kkt_select": 1,
-             "decision": 3, "multitask_decision": 4}[kernel]
+    arity = {"rbf_gram": 3, "rff_features": 3, "decision": 3,
+             "multitask_decision": 4}[kernel]
     parts = tuple(int(p) for p in text.lower().split("x"))
     if len(parts) != arity or any(p <= 0 for p in parts):
         raise ValueError(

@@ -13,9 +13,10 @@ async dynamic-batching service layer.
     reg = serve.ModelRegistry(max_resident=4)   # multi-model LRU residency
 
 See ``serve.artifact`` for the artifact schema (v1/v2/v3 + SV-bank
-quantization), ``serve.predictor`` for the bucket/jit-cache behavior,
-``serve.registry`` for LRU device residency and ``serve.service`` for
-the batching-window semantics.
+quantization), ``serve.reference`` for the float64 decision oracle
+served values are held to, ``serve.predictor`` for the bucket/jit-cache
+behavior, ``serve.registry`` for LRU device residency and
+``serve.service`` for the batching-window semantics.
 """
 from repro.serve.artifact import (LowRankMap, PackedModel,  # noqa: F401
                                   TaskBucket, SCHEMA_NAME, SCHEMA_VERSION,
@@ -24,4 +25,5 @@ from repro.serve.artifact import (LowRankMap, PackedModel,  # noqa: F401
                                   SV_DTYPES, load, pack, quantize, save)
 from repro.serve.predictor import Predictor, serving_config  # noqa: F401
 from repro.serve.registry import ModelRegistry  # noqa: F401
+from repro.serve import reference  # noqa: F401
 from repro.serve.service import ServingService  # noqa: F401

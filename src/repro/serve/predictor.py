@@ -9,7 +9,8 @@ in Python — fine for evaluating a fit, hopeless under request traffic.
   at construction, and stays resident;
 * decisions run through ONE jitted program per (bucket shape,
   batch bucket) static configuration — for the pallas backend the fused
-  multi-task kernel (``kernels.ops.multitask_decision``), which
+  multi-task kernel (``kernels.ops.multitask_decision``, RBF and
+  linear kernels; any other kernel is refused at construction), which
   evaluates every stacked task of a bucket against the test batch in a
   single grid; for chunked/dense configs a vmapped ``engine.decide``
   (the reference/fallback path, numerically identical to the legacy
@@ -57,6 +58,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import kernel_engine as KE
+from repro.core import kernels as K
 from repro.core import multiclass as MC
 from repro.kernels import ops
 from repro.serve.artifact import PackedModel
@@ -102,6 +104,13 @@ class Predictor:
         # never exceeds what the caller asked for.
         self.max_batch = _pow2_floor(max_batch)
         self.engine_cfg = serving_config(engine)
+        if (self.engine_cfg.backend == "pallas"
+                and model.feature_map is None
+                and model.kernel.name not in ("rbf", "linear")):
+            raise ValueError(
+                f"engine='pallas' serves the rbf and linear kernels; "
+                f"this model's kernel is {model.kernel.name!r} — serve it "
+                f"with engine='chunked'")
         # SV banks move to device once and stay resident; task_ids stay
         # host-side (they only scatter results back into request order)
         self._banks = tuple(
@@ -123,7 +132,7 @@ class Predictor:
                 from repro.core import approx
                 m = approx.map_from_arrays(kind, kp, a, b,
                                            gram_dtype=gram_dtype)
-                return (m.transform(z) @ w.T).T + lb[:, None]
+                return K.f32_dot(m.transform(z), w.T).T + lb[:, None]
 
             self._decide_lowrank = jax.jit(lowrank_decide)
         # one jitted callable; XLA caches one executable per distinct
@@ -145,9 +154,9 @@ class Predictor:
         sv_x = sv_x.astype(jnp.float32)
         sv_coef = sv_coef.astype(jnp.float32)
         kp = self.model.kernel
-        if self.engine_cfg.backend == "pallas" and kp.name == "rbf":
+        if self.engine_cfg.backend == "pallas":
             return ops.multitask_decision(
-                z, sv_x, sv_coef, b, gamma=kp.gamma, mode="rbf",
+                z, sv_x, sv_coef, b, gamma=kp.gamma, mode=kp.name,
                 compute_dtype=self.engine_cfg.gram_dtype)
 
         def one(sv, cf, bb):

@@ -24,7 +24,7 @@ import pytest
 from repro.kernels import autotune, ops
 from repro.kernels import rbf_gram as G
 from repro.kernels import decision as D
-from repro.kernels import kkt_select as KS
+from repro.roofline.collect import V5E
 
 
 @pytest.fixture
@@ -36,6 +36,16 @@ def isolated_cache(tmp_path):
     autotune.set_cache_path(None)
 
 
+@pytest.fixture
+def priced_as_v5e(monkeypatch):
+    """``tune`` prices the attached device; a CPU has no published
+    peaks, so stand the attached device in for the v5e. Both module
+    objects: the import-purity test below re-imports ``autotune``."""
+    import repro.kernels
+    for mod in {autotune, repro.kernels.autotune}:
+        monkeypatch.setattr(mod, "device_kind", lambda: V5E)
+
+
 def _tune_tiny(kernel="rbf_gram", shape=(256, 256, 128)):
     return autotune.tune(kernel, shape, dtype="fp32", budget=4,
                          objective="roofline")
@@ -44,7 +54,6 @@ def _tune_tiny(kernel="rbf_gram", shape=(256, 256, 128)):
 # ------------------------------------------------------------- candidates
 def test_candidates_include_default_and_fit_vmem():
     for kernel, shape in [("rbf_gram", (2048, 2048, 256)),
-                          ("kkt_select", (8192,)),
                           ("decision", (512, 4096, 128)),
                           ("multitask_decision", (8, 256, 1024, 128))]:
         cands = autotune.candidates(kernel, shape)
@@ -74,7 +83,8 @@ def test_bf16_admits_wider_tiles_than_fp32():
 def test_shape_bucket_and_cache_key():
     assert autotune.shape_bucket("rbf_gram", (1000, 1024, 100)) == \
         "n1024_m1024_d128"
-    assert autotune.shape_bucket("kkt_select", (5000,)) == "n8192"
+    assert autotune.shape_bucket("rff_features", (5000, 1000, 102)) == \
+        "n8192_k1024_d128"
     key = autotune.cache_key("cpu", "rbf_gram", "bf16", (1000, 1024, 100))
     assert key == "cpu|rbf_gram|bf16|n1024_m1024_d128"
     with pytest.raises(ValueError):
@@ -82,7 +92,13 @@ def test_shape_bucket_and_cache_key():
 
 
 # -------------------------------------------------------------- hillclimb
-def test_tune_roofline_never_worse_than_default():
+def test_tune_refuses_a_device_without_peaks(monkeypatch):
+    monkeypatch.setattr(autotune, "device_kind", lambda: "cpu")
+    with pytest.raises(ValueError, match="no published peaks"):
+        autotune.tune("rbf_gram", (256, 256, 128), objective="roofline")
+
+
+def test_tune_roofline_never_worse_than_default(priced_as_v5e):
     for kernel, shape in [("rbf_gram", (1024, 1024, 128)),
                           ("decision", (256, 2048, 128))]:
         res = autotune.tune(kernel, shape, budget=6, objective="roofline")
@@ -93,7 +109,7 @@ def test_tune_roofline_never_worse_than_default():
         assert res.best.config in autotune.candidates(kernel, shape)
 
 
-def test_tune_wall_objective_measures_and_improves():
+def test_tune_wall_objective_measures_and_improves(priced_as_v5e):
     # tiny shape so interpret-mode timing stays cheap; the guarantee is
     # structural (default evaluated first), not a perf claim on CPU
     res = autotune.tune("rbf_gram", (128, 128, 64), budget=2,
@@ -107,20 +123,23 @@ def test_roofline_estimate_rewards_bigger_tiles_and_bf16():
     shape = (4096, 4096, 256)
     small = autotune.roofline_estimate("rbf_gram", shape, "fp32",
                                        {"block_n": 128, "block_m": 128,
-                                        "block_d": 128})
+                                        "block_d": 128},
+                                       device_kind=V5E)
     big = autotune.roofline_estimate("rbf_gram", shape, "fp32",
                                      {"block_n": 512, "block_m": 512,
-                                      "block_d": 128})
+                                      "block_d": 128},
+                                     device_kind=V5E)
     assert big["hbm_bytes"] < small["hbm_bytes"]
     assert big["flops"] == small["flops"]
     bf16 = autotune.roofline_estimate("rbf_gram", shape, "bf16",
                                       {"block_n": 128, "block_m": 128,
-                                       "block_d": 128})
+                                       "block_d": 128},
+                                      device_kind=V5E)
     assert bf16["hbm_bytes"] < small["hbm_bytes"]
 
 
 # ------------------------------------------------------------- disk cache
-def test_cache_roundtrip(isolated_cache):
+def test_cache_roundtrip(isolated_cache, priced_as_v5e):
     res = _tune_tiny()
     cache = autotune.TuningCache()
     key = autotune.cache_key("cpu", "rbf_gram", "fp32", (256, 256, 128))
@@ -183,7 +202,8 @@ def test_malformed_entries_are_dropped(isolated_cache):
 
 
 # ------------------------------------------------------ runtime fast path
-def test_ops_pick_up_tuned_entry_and_stay_correct(isolated_cache):
+def test_ops_pick_up_tuned_entry_and_stay_correct(isolated_cache,
+                                                  priced_as_v5e):
     """A tuned non-default tile must change only the schedule: the Gram
     values from the tuned path match the default-tile values exactly."""
     shape = (256, 200, 64)
@@ -210,7 +230,7 @@ def test_ops_pick_up_tuned_entry_and_stay_correct(isolated_cache):
     np.testing.assert_allclose(tuned, baseline, rtol=0, atol=1e-6)
 
 
-def test_explicit_blocks_override_tuned_entry(isolated_cache):
+def test_explicit_blocks_override_tuned_entry(isolated_cache, priced_as_v5e):
     shape = (256, 256, 128)
     cache = autotune.TuningCache()
     cache.put(autotune.cache_key(autotune.device_kind(), "rbf_gram",
@@ -231,6 +251,30 @@ def test_env_var_overrides_cache_location(tmp_path, monkeypatch):
     assert autotune.default_cache_path() == p
 
 
+def test_default_cache_lives_in_the_checkout(monkeypatch):
+    monkeypatch.delenv("REPRO_TUNE_CACHE", raising=False)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert autotune.default_cache_path() == os.path.join(root,
+                                                         ".autotune.json")
+
+
+def test_lookup_propagates_device_errors(isolated_cache, priced_as_v5e,
+                                         monkeypatch):
+    # a readable cache with entries, then a failing device query: the
+    # error surfaces instead of silently meaning "untuned"
+    cache = autotune.TuningCache()
+    cache.put(autotune.cache_key("cpu", "rbf_gram", "fp32",
+                                 (256, 256, 128)), _tune_tiny())
+    cache.save(isolated_cache)
+    autotune.reset()
+
+    def no_device():
+        raise RuntimeError("no device")
+    monkeypatch.setattr(autotune, "device_kind", no_device)
+    with pytest.raises(RuntimeError, match="no device"):
+        autotune.lookup("rbf_gram", (256, 256, 128))
+
+
 # ------------------------------------- uniform misaligned-shape ValueErrors
 def test_direct_pallas_calls_raise_on_misaligned_shapes():
     z = jnp.zeros
@@ -246,9 +290,6 @@ def test_direct_pallas_calls_raise_on_misaligned_shapes():
     with pytest.raises(ValueError, match="pre-padded to block multiples"):
         D.multitask_decision_pallas(z((128, 128)), z((2, 100, 128)),
                                     z((2, 100)), gamma=1.0, interpret=True)
-    with pytest.raises(ValueError, match="pre-padded to block multiples"):
-        KS.kkt_select_pallas(z(100), z(100), z(100), z(100, jnp.int32),
-                             c=1.0, block=128, interpret=True)
     with pytest.raises(ValueError, match="feature dims"):
         G.rbf_gram_pallas(z((128, 128)), z((128, 256)), gamma=1.0,
                           interpret=True)
@@ -283,7 +324,7 @@ def test_setup_env_is_idempotent(monkeypatch):
 
 
 # ------------------------------------------------------------- CLI driver
-def test_svm_tune_cli_writes_cache(tmp_path):
+def test_svm_tune_cli_writes_cache(tmp_path, priced_as_v5e):
     from repro.roofline import svm_tune
     out = str(tmp_path / "cli.json")
     rc = svm_tune.main(["--kernel", "rbf_gram", "--shape", "256x256x128",
@@ -303,4 +344,4 @@ def test_svm_tune_cli_rejects_bad_shape():
     with pytest.raises(ValueError, match="positive 'x'-separated"):
         svm_tune.parse_shape("rbf_gram", "256x256")
     with pytest.raises(ValueError):
-        svm_tune.parse_shape("kkt_select", "0")
+        svm_tune.parse_shape("decision", "0x128x128")

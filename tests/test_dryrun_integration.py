@@ -8,7 +8,7 @@ import pytest
 from jax.sharding import NamedSharding
 
 from repro.configs.base import get_config, reduced
-from repro.launch.mesh import set_mesh
+from repro.launch.mesh import make_mesh
 from repro.models.model import Model, abstract_init
 from repro.roofline.collect import collective_bytes
 from repro.sharding import rules
@@ -18,7 +18,7 @@ from repro.sharding import rules
 @pytest.mark.parametrize("arch", ["phi4_mini_3p8b", "qwen2_moe_a2p7b",
                                   "mamba2_780m"])
 def test_reduced_dryrun_on_2x4_mesh(arch):
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     cfg = reduced(get_config(arch))
     model = Model(cfg)
     params_shapes, logical = abstract_init(model)
@@ -36,7 +36,7 @@ def test_reduced_dryrun_on_2x4_mesh(arch):
     def fwd(p, b):
         return model.forward(p, b)[0]
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(fwd).lower(params_shapes, batch)
         compiled = lowered.compile()
     mem = compiled.memory_analysis()

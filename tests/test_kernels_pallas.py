@@ -37,33 +37,6 @@ def test_linear_gram_sweep(n, m, d):
                                atol=1e-4)
 
 
-@pytest.mark.parametrize("block", [128, 256])
-@pytest.mark.parametrize("n", [64, 500, 1024, 4096])
-def test_kkt_select_sweep(n, block):
-    f = RNG.normal(size=(n,)).astype(np.float32)
-    alpha = RNG.uniform(0, 1, size=(n,)).astype(np.float32)
-    alpha[RNG.random(n) < 0.3] = 0.0
-    alpha[RNG.random(n) < 0.2] = 1.0
-    y = np.where(RNG.random(n) < 0.5, 1.0, -1.0).astype(np.float32)
-    mask = RNG.random(n) < 0.9
-    got = ops.kkt_select(jnp.asarray(f), jnp.asarray(alpha),
-                         jnp.asarray(y), jnp.asarray(mask), c=1.0,
-                         block=block)
-    want = ref.kkt_select(jnp.asarray(f), jnp.asarray(alpha),
-                          jnp.asarray(y), jnp.asarray(mask), 1.0)
-    assert float(got[0]) == pytest.approx(float(want[0]), abs=1e-6)
-    assert float(got[2]) == pytest.approx(float(want[2]), abs=1e-6)
-    assert int(got[1]) == int(want[1])
-    assert int(got[3]) == int(want[3])
-
-
-def test_kkt_select_all_masked():
-    n = 256
-    got = ops.kkt_select(jnp.zeros(n), jnp.zeros(n), jnp.ones(n),
-                         jnp.zeros(n, bool), c=1.0)
-    assert np.isinf(float(got[0])) and np.isinf(float(got[2]))
-
-
 @pytest.mark.parametrize("nt,n,d", [(64, 64, 4), (200, 333, 102),
                                     (13, 1000, 32)])
 def test_decision_sweep(nt, n, d):
@@ -183,7 +156,7 @@ def test_ssd_diag_sweep(bc, h, q, n, p):
     cs = np.cumsum(dt * a[None, :, None], axis=2).astype(np.float32)
     got = _sd.ssd_diag_pallas(jnp.asarray(cmat), jnp.asarray(bmat),
                               jnp.asarray(x), jnp.asarray(dt),
-                              jnp.asarray(cs))
+                              jnp.asarray(cs), interpret=True)
     want = ref.ssd_diag(jnp.asarray(cmat), jnp.asarray(bmat),
                         jnp.asarray(x), jnp.asarray(dt), jnp.asarray(cs))
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -208,7 +181,7 @@ def test_ssd_diag_matches_model_chunked_path():
         jnp.asarray(cm[:, :, 0, :]), jnp.asarray(bm[:, :, 0, :]),
         jnp.asarray(x.transpose(0, 2, 1, 3)),
         jnp.asarray(dt.transpose(0, 2, 1)),
-        jnp.asarray(cs.transpose(0, 2, 1)))
+        jnp.asarray(cs.transpose(0, 2, 1)), interpret=True)
     want = np.asarray(y).transpose(0, 2, 1, 3)             # (B,H,S,P)
     np.testing.assert_allclose(np.asarray(got), want, rtol=5e-3,
                                atol=5e-4)

@@ -1,12 +1,12 @@
 """OvO multiclass + the distributed (shard_map) MPI layer."""
 import numpy as np
-import jax
 import jax.numpy as jnp
 import pytest
 
 from repro.core import dist, kernels as K, ovo
 from repro.core.svm import SVC
 from repro.data import load_iris, load_pavia_like, normalize
+from repro.launch.mesh import make_local_mesh
 
 
 def test_vote_matches_majority():
@@ -61,7 +61,7 @@ def test_distributed_equals_local_4workers():
     x, y = load_pavia_like(n_per_class=24, n_classes=5)
     x = normalize(x)
     kp = K.resolve_gamma(K.KernelParams(), jnp.asarray(x))
-    mesh = jax.make_mesh((4,), ("workers",))
+    mesh = make_local_mesh(4)
     tasks = ovo.build_tasks(x, y, pad_tasks_to=4)
     fit = dist.distributed_ovo_fit(tasks, mesh, ("workers",),
                                    solver="smo", kernel=kp)

@@ -28,6 +28,7 @@ import numpy as np
 import pytest
 
 from repro import serve
+from repro.serve import reference
 from repro.core.svm import SVC, SVR
 from repro.data.synth import (make_blobs, make_imbalanced_blobs,
                               make_synth_regression)
@@ -66,17 +67,27 @@ def test_service_matches_predictor_outputs(ovo_problem):
                   "decision_function", i, 1) for i in range(24, 30)]
         futs += [(svc.submit(x[i:i + 2], op="values"), "values", i, 2)
                  for i in range(30, 40, 2)]
+        tol = reference.tolerance(packed)
+        ulps = 8 * np.spacing((tol / reference.RTOL).astype(np.float32))
         for fut, op, i, n in futs:
             got = fut.result(timeout=30)
             want = pred.decode(pred.decision_values(x[i:i + n]), op)
+            ref = reference.decision_values(packed, x[i:i + n])
             if op == "predict":
                 np.testing.assert_array_equal(got, want)
+                np.testing.assert_array_equal(
+                    got, pred.decode(ref.astype(np.float32), op))
             else:
                 # the merged batch pads to a different bucket than the
-                # per-slice reference: multi-task chunked values may
-                # move a few ulp (documented in tests/test_serving.py)
-                np.testing.assert_array_almost_equal_nulp(got, want,
-                                                          nulp=8)
+                # per-slice call, so a task's sum may round in another
+                # order: hold the two to 8 f32 ulps of the sum's scale
+                # 1 + ||coef_t||_1 (ulps of a value near 0 are far finer
+                # than that rounding)
+                got = np.reshape(got, ref.shape)
+                gap = np.abs(got - np.reshape(want, ref.shape))
+                assert (gap <= ulps).all(), (op, float(gap.max()))
+                err = np.abs(got - ref)
+                assert (err <= tol).all(), (op, float(err.max()))
 
 
 def test_service_batches_a_burst(binary_problem):

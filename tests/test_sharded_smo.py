@@ -24,7 +24,7 @@ from jax.sharding import PartitionSpec as P
 from repro.core import dist, kernel_engine as KE, kernels as K, smo
 from repro.core.svm import SVC
 from repro.data import make_blobs, normalize
-from repro.launch.mesh import make_shard_mesh
+from repro.launch.mesh import make_local_mesh, make_shard_mesh
 
 SV_EPS = 1e-6
 
@@ -91,13 +91,17 @@ def test_equivalence_matrix(kernel_name, ref_backend, n_shards):
 
 
 @pytest.mark.requires_devices(4)
-def test_non_divisible_n_padding_edge():
+@pytest.mark.parametrize("axis_type", ["auto", "explicit"])
+def test_non_divisible_n_padding_edge(axis_type):
     # 519 % 4 == 3: the sample axis is zero-padded to 520 and the pad
-    # rows must stay masked with alpha identically 0
+    # rows must stay masked with alpha identically 0. A caller's own
+    # jax.make_mesh has Explicit axes: unpadding must work there too.
     x, yy = _binary_problem(519)
     kp = K.resolve_gamma(K.KernelParams(), jnp.asarray(x))
     ref = smo.binary_smo(jnp.asarray(x), jnp.asarray(yy), kernel=kp)
-    got = smo.sharded_binary_smo(x, yy, mesh=make_shard_mesh(4), kernel=kp)
+    mesh = (make_shard_mesh(4) if axis_type == "auto"
+            else jax.make_mesh((4,), ("shards",)))
+    got = smo.sharded_binary_smo(x, yy, mesh=mesh, kernel=kp)
     assert got.alpha.shape == (519,)
     _assert_equivalent(ref, got, x=x, yy=yy, kp=kp)
 
@@ -261,8 +265,10 @@ def test_sharded_engine_row_matvec_decide_match_dense():
         return eng.matvec(v_l), eng.decide(z, coef_l, 0.25), row
 
     spec = P("s")
-    fn = jax.jit(KE.shard_map_compat(body, mesh, (spec, spec, spec),
-                                     (spec, P(), spec)))
+    fn = jax.jit(jax.shard_map(body, mesh=mesh,
+                               in_specs=(spec, spec, spec),
+                               out_specs=(spec, P(), spec),
+                               check_vma=False))
     mv, dec, row = fn(x, v, coef)
     np.testing.assert_allclose(np.asarray(mv),
                                np.asarray(dense.matvec(v)),
@@ -300,7 +306,7 @@ def test_fit_taskset_data_parallel_matches_task_parallel():
     from repro.core import multiclass as MC
     kp = K.resolve_gamma(K.KernelParams(), jnp.asarray(x))
     taskset = MC.get_strategy("ovo").build_taskset(x, y)
-    mesh = jax.make_mesh((4,), ("workers",))
+    mesh = make_local_mesh(4)
     ref = dist.fit_taskset(taskset, kernel=kp)  # local vmapped
     got = dist.fit_taskset(taskset, mesh=mesh, kernel=kp, shard="data")
     np.testing.assert_allclose(got.alpha, ref.alpha, rtol=1e-4, atol=1e-5)
@@ -320,7 +326,7 @@ def test_fit_taskset_data_parallel_validates():
     y = np.repeat(np.arange(3), 20)
     from repro.core import multiclass as MC
     taskset = MC.get_strategy("ovo").build_taskset(x, y)
-    mesh = jax.make_mesh((4,), ("workers",))
+    mesh = make_local_mesh(4)
     with pytest.raises(ValueError, match="solver='smo'"):
         dist.fit_taskset(taskset, mesh=mesh, solver="gd", shard="data")
     with pytest.raises(ValueError, match="shard mode"):

@@ -13,7 +13,8 @@ from repro.data import (load_breast_cancer_like, load_iris,
 from repro.data.lm import token_batches
 from repro.data.pipeline import subsample_per_class
 from repro.optim.adamw import AdamW, SGD, cosine_schedule, global_norm
-from repro.roofline.collect import (collective_bytes, roofline_terms)
+from repro.roofline.collect import (V5E, collective_bytes, peaks,
+                                    roofline_terms)
 from repro.training.train import cross_entropy
 
 
@@ -143,12 +144,44 @@ class TestRooflineParsing:
 
     def test_roofline_dominance(self):
         t = roofline_terms(flops=197e12, hbm_bytes=1.0,
-                           collective_bytes_total=1.0)
+                           collective_bytes_total=1.0, device_kind=V5E)
         assert t["dominant"] == "compute"
         assert t["t_compute_s"] == pytest.approx(1.0)
         t = roofline_terms(flops=1.0, hbm_bytes=819e9,
-                           collective_bytes_total=1.0)
+                           collective_bytes_total=1.0, device_kind=V5E)
         assert t["dominant"] == "memory"
         t = roofline_terms(flops=1.0, hbm_bytes=1.0,
-                           collective_bytes_total=200e9)
+                           collective_bytes_total=200e9, device_kind=V5E)
         assert t["dominant"] == "collective"
+
+    def test_peaks_unknown_device_kind_is_an_error(self):
+        assert peaks(V5E)["flops_bf16"] == 197e12
+        with pytest.raises(ValueError, match="no published peaks"):
+            peaks("cpu")
+        with pytest.raises(ValueError, match="no published peaks"):
+            roofline_terms(flops=1.0, hbm_bytes=1.0,
+                           collective_bytes_total=0.0, device_kind="cpu")
+
+
+class TestCompileCache:
+    @pytest.fixture
+    def restore_dir(self):
+        prev = jax.config.jax_compilation_cache_dir
+        yield
+        jax.config.update("jax_compilation_cache_dir", prev)
+
+    def test_env_var_wins_and_nothing_is_set(self, monkeypatch, tmp_path,
+                                            restore_dir):
+        from repro.launch import compile_cache
+        before = jax.config.jax_compilation_cache_dir
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert compile_cache.enable("/elsewhere") == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+
+    def test_default_is_fixed_dir_in_the_checkout(self, monkeypatch,
+                                                  tmp_path, restore_dir):
+        from repro.launch import compile_cache
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = str(tmp_path.resolve() / ".jax_cache")
+        assert compile_cache.enable(tmp_path) == want
+        assert jax.config.jax_compilation_cache_dir == want

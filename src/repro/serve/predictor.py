@@ -205,26 +205,34 @@ class Predictor:
         nt = xt.shape[0]
         out = np.empty((self.model.n_tasks, nt), np.float32)
         sigs = []
+        # host spans per slice (and bank): the pad and upload, the jitted
+        # call, and the fetch that waits for the device and copies back
+        span = jax.profiler.TraceAnnotation
         for start in range(0, nt, self.max_batch):
             stop = min(start + self.max_batch, nt)
             bucket = self._batch_bucket(stop - start)
-            zp = np.zeros((bucket, xt.shape[1]), np.float32)
-            zp[:stop - start] = xt[start:stop]
-            zj = jnp.asarray(zp)
+            with span("serve.upload"):
+                zp = np.zeros((bucket, xt.shape[1]), np.float32)
+                zp[:stop - start] = xt[start:stop]
+                zj = jnp.asarray(zp)
             if self.model.feature_map is not None:
                 a, fb = self._fm_arrays
                 w, lb = self._linear
-                df = self._decide_lowrank(a, fb, w, lb, zj)
-                out[:, start:stop] = np.asarray(df)[:, :stop - start]
+                with span("serve.launch"):
+                    df = self._decide_lowrank(a, fb, w, lb, zj)
+                with span("serve.fetch"):
+                    out[:, start:stop] = np.asarray(df)[:, :stop - start]
                 sigs.append(("lowrank", bucket))
                 continue
             for sv_x, sv_coef, b, task_ids in self._banks:
                 if sv_x.shape[1] == 0:  # empty-SV bank: constant bias
                     out[task_ids, start:stop] = np.asarray(b)[:, None]
                     continue
-                df = self._decide(sv_x, sv_coef, b, zj)
-                out[task_ids, start:stop] = np.asarray(
-                    df)[:, :stop - start]
+                with span("serve.launch"):
+                    df = self._decide(sv_x, sv_coef, b, zj)
+                with span("serve.fetch"):
+                    out[task_ids, start:stop] = np.asarray(
+                        df)[:, :stop - start]
                 sigs.append((sv_x.shape, str(sv_x.dtype), bucket))
         with self._lock:
             self._program_sigs.update(sigs)

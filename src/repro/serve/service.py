@@ -32,9 +32,22 @@ the greedy-backlog batcher. The latency cost of a window is bounded by
     svc.close()                               # flushes, then stops
 
 Multi-model form: pass a ``ModelRegistry`` (or a ``{name: PackedModel}``
-dict) and route with ``submit(x, model="name")``. ``stats`` reports the
-request/batch/row counters the open-loop benchmark
-(``benchmarks.bench_serving_load``) builds its p50/p99 story on.
+dict) and route with ``submit(x, model="name")``.
+
+For operators, ``stats`` holds cumulative counters: requests, rows and
+batches served (``rows_per_batch``), window against full flushes,
+``n_failed_requests`` (futures that got a decide or decode error),
+``queue_wait_s`` (summed from each ``submit`` to the batcher taking the
+request), and the batcher's host seconds per stage: ``batch_s`` in all,
+``collect_s``, ``merge_s``, ``decide_s``, ``decode_s``, ``scatter_s``,
+and ``batcher_cpu_s``, its thread's CPU time inside batches. Each stage
+is also a ``jax.profiler.TraceAnnotation`` (``serve.trace``), so a
+profiler trace shows, on the device's clock, ``serve.batch`` (metadata
+``batch``, ``requests``, ``rows``, ``full``) and nested in it
+``serve.collect``, ``serve.merge``, ``serve.decide`` (the predictor's
+``serve.upload``, ``serve.launch``, ``serve.fetch`` inside),
+``serve.decode`` and ``serve.scatter``. The batcher's blocking wait for
+a first request lies outside every span.
 """
 from __future__ import annotations
 
@@ -49,6 +62,7 @@ import numpy as np
 from repro.serve.artifact import PackedModel
 from repro.serve.predictor import Predictor, _pow2_floor
 from repro.serve.registry import ModelRegistry
+from repro.serve.trace import Stages
 
 _OPS = ("predict", "decision_function", "values")
 _SENTINEL = object()
@@ -59,6 +73,7 @@ class _Request(NamedTuple):
     op: str
     x: np.ndarray          # (n, d) float32
     future: Future
+    t_submit: float        # time.perf_counter() at submit
 
 
 class ServingService:
@@ -94,7 +109,11 @@ class ServingService:
         self._stats_lock = threading.Lock()
         self._stats = {"n_requests": 0, "n_rows": 0, "n_batches": 0,
                        "n_window_flushes": 0, "n_full_flushes": 0,
-                       "max_batch_rows": 0}
+                       "max_batch_rows": 0, "n_failed_requests": 0,
+                       "queue_wait_s": 0.0, "batch_s": 0.0,
+                       "batcher_cpu_s": 0.0, "collect_s": 0.0,
+                       "merge_s": 0.0, "decide_s": 0.0, "decode_s": 0.0,
+                       "scatter_s": 0.0}
         self._worker = threading.Thread(target=self._run,
                                         name="repro-serving-batcher",
                                         daemon=True)
@@ -128,7 +147,7 @@ class ServingService:
             raise ValueError(f"expected a non-empty (n, {d}) request "
                              f"for model {model!r}, got shape {x.shape}")
         fut: Future = Future()
-        self._q.put(_Request(model, op, x, fut))
+        self._q.put(_Request(model, op, x, fut, time.perf_counter()))
         return fut
 
     # ------------------------------------------------- blocking shortcuts
@@ -193,55 +212,82 @@ class ServingService:
         return _pow2_floor(self.registry.max_batch)
 
     def _run(self) -> None:
+        n_batch = 0
         while True:
-            req = self._q.get()
+            req = self._q.get()        # idle: outside every span
             if req is _SENTINEL:
                 return
-            pending = [req]
-            rows = {req.model: req.x.shape[0]}
-            deadline = time.perf_counter() + self.window_s
-            full = req.x.shape[0] >= self._cap(req.model)
-            while not full:
-                try:
-                    # drain the backlog greedily first (this is all the
-                    # batching window_ms=0 gets), then wait the window
-                    nxt = self._q.get_nowait()
-                except queue.Empty:
-                    remaining = deadline - time.perf_counter()
-                    if remaining <= 0:
-                        break
-                    try:
-                        nxt = self._q.get(timeout=remaining)
-                    except queue.Empty:
-                        break
-                if nxt is _SENTINEL:
-                    self._flush(pending)
-                    return
-                pending.append(nxt)
-                rows[nxt.model] = rows.get(nxt.model, 0) + nxt.x.shape[0]
-                full = rows[nxt.model] >= self._cap(nxt.model)
+            n_batch += 1
+            stages = Stages()
+            cpu0 = time.thread_time()
+            with stages("serve.batch", "batch_s", batch=n_batch) as batch:
+                stages.add("queue_wait_s", time.perf_counter() - req.t_submit)
+                with stages("serve.collect", "collect_s"):
+                    pending, full, closing = self._collect(req, stages)
+                batch.set_metadata(requests=len(pending),
+                                   rows=sum(r.x.shape[0] for r in pending),
+                                   full=full)
+                self._flush(pending, stages)
+            stages.add("batcher_cpu_s", time.thread_time() - cpu0)
             with self._stats_lock:
-                self._stats["n_full_flushes" if full
-                            else "n_window_flushes"] += 1
-            self._flush(pending)
+                if not closing:
+                    self._stats["n_full_flushes" if full
+                                else "n_window_flushes"] += 1
+                for k, v in stages.totals.items():
+                    self._stats[k] += v
+            if closing:
+                return
 
-    def _flush(self, pending: list) -> None:
+    def _collect(self, req: _Request, stages: Stages):
+        """The window opened by ``req``: drain the backlog greedily (all
+        the batching ``window_ms=0`` gets), then wait out the window,
+        until some model's rows reach its cap. Returns the requests,
+        whether the window filled, and whether ``close`` ended it."""
+        pending = [req]
+        rows = {req.model: req.x.shape[0]}
+        deadline = time.perf_counter() + self.window_s
+        full = req.x.shape[0] >= self._cap(req.model)
+        while not full:
+            try:
+                nxt = self._q.get_nowait()
+            except queue.Empty:
+                remaining = deadline - time.perf_counter()
+                if remaining <= 0:
+                    break
+                try:
+                    nxt = self._q.get(timeout=remaining)
+                except queue.Empty:
+                    break
+            if nxt is _SENTINEL:
+                return pending, full, True
+            stages.add("queue_wait_s", time.perf_counter() - nxt.t_submit)
+            pending.append(nxt)
+            rows[nxt.model] = rows.get(nxt.model, 0) + nxt.x.shape[0]
+            full = rows[nxt.model] >= self._cap(nxt.model)
+        return pending, full, False
+
+    def _flush(self, pending: list, stages: Stages) -> None:
         """One fused decide + vectorized decode per model present, then
         scatter per-request slices back through the futures."""
-        by_model: dict[str, list] = {}
-        for r in pending:
-            by_model.setdefault(r.model, []).append(r)
+        with stages("serve.merge", "merge_s"):
+            by_model: dict[str, list] = {}
+            for r in pending:
+                by_model.setdefault(r.model, []).append(r)
         for name, reqs in by_model.items():
             try:
                 pred = self._predictor(name)
-                xcat = (reqs[0].x if len(reqs) == 1
-                        else np.concatenate([r.x for r in reqs], axis=0))
-                df = pred.decision_values(xcat)
+                with stages("serve.merge", "merge_s"):
+                    xcat = (reqs[0].x if len(reqs) == 1
+                            else np.concatenate([r.x for r in reqs], axis=0))
+                with stages("serve.decide", "decide_s"):
+                    df = pred.decision_values(xcat)
                 # decode ONCE per op over the merged batch (every op is
                 # columnwise), then slice per request
-                decoded = {op: pred.decode(df, op)
-                           for op in {r.op for r in reqs}}
+                with stages("serve.decode", "decode_s"):
+                    decoded = {op: pred.decode(df, op)
+                               for op in {r.op for r in reqs}}
             except Exception as e:                 # noqa: BLE001
+                stages.add("n_failed_requests", len(reqs))
                 for r in reqs:
                     if not r.future.cancelled():
                         r.future.set_exception(e)
@@ -252,11 +298,13 @@ class ServingService:
                 self._stats["n_batches"] += 1
                 self._stats["max_batch_rows"] = max(
                     self._stats["max_batch_rows"], xcat.shape[0])
-            start = 0
-            for r in reqs:
-                stop = start + r.x.shape[0]
-                out = decoded[r.op]
-                sl = out[..., start:stop] if out.ndim > 1 else out[start:stop]
-                start = stop
-                if not r.future.cancelled():
-                    r.future.set_result(sl)
+            with stages("serve.scatter", "scatter_s"):
+                start = 0
+                for r in reqs:
+                    stop = start + r.x.shape[0]
+                    out = decoded[r.op]
+                    sl = (out[..., start:stop] if out.ndim > 1
+                          else out[start:stop])
+                    start = stop
+                    if not r.future.cancelled():
+                        r.future.set_result(sl)

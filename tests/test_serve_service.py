@@ -547,3 +547,138 @@ def test_service_replay_stays_within_compile_budget(ovo_problem,
             for f in futs:
                 f.result(timeout=60)
         assert g.count == 0
+
+
+# ------------------------------------------------------- spans and counters
+STAGE_KEYS = ("collect_s", "merge_s", "decide_s", "decode_s", "scatter_s")
+TIME_KEYS = STAGE_KEYS + ("batch_s", "batcher_cpu_s", "queue_wait_s")
+
+
+def _held_predictor(model, gate: threading.Event, fail: bool = False):
+    """A predictor whose decide waits for ``gate`` (and then raises, if
+    ``fail``): the batcher is held inside its first batch."""
+    pred = serve.Predictor(serve.pack(model), engine="chunked").warmup(
+        (1, 2, 4, 8))
+    decide = pred.decision_values
+
+    def held(xt):
+        assert gate.wait(timeout=30)
+        if fail:
+            raise RuntimeError("planted decide failure")
+        return decide(xt)
+
+    pred.decision_values = held
+    return pred
+
+
+def test_service_stage_counters(ovo_problem):
+    x, _, model = ovo_problem
+    with serve.ServingService(serve.pack(model), engine="chunked",
+                              window_ms=2.0) as svc:
+        futs = [svc.submit(x[i:i + 1 + i % 3],
+                           op=("predict", "values")[i % 2])
+                for i in range(24)]
+        for f in futs:
+            f.result(timeout=30)
+    s = svc.stats
+    for k in TIME_KEYS + ("n_failed_requests",):
+        assert k in s and s[k] >= 0, k
+    assert s["n_failed_requests"] == 0
+    assert s["batch_s"] > 0 and s["decide_s"] > 0 and s["decode_s"] > 0
+    # the stages are disjoint parts of the batches
+    assert sum(s[k] for k in STAGE_KEYS) <= s["batch_s"]
+    assert s["batcher_cpu_s"] <= s["batch_s"] * 1.05 + 1e-3
+
+
+def test_service_queue_wait_grows_when_the_batcher_is_held(binary_problem):
+    x, _, model = binary_problem
+    gate = threading.Event()
+    svc = serve.ServingService(_held_predictor(model, gate), window_ms=0.0)
+    try:
+        first = svc.submit(x[:1])
+        time.sleep(0.1)                # the batcher takes it and blocks
+        later = [svc.submit(x[i]) for i in range(1, 4)]
+        held_s = 0.3
+        time.sleep(held_s)
+        gate.set()
+        for f in [first] + later:
+            f.result(timeout=30)
+    finally:
+        svc.close(timeout=30)
+    s = svc.stats
+    # each of the three later requests waited out the hold in the queue
+    assert s["queue_wait_s"] >= 3 * held_s
+    assert s["n_requests"] == 4
+
+
+def test_service_counts_failed_requests(binary_problem):
+    x, _, model = binary_problem
+    gate = threading.Event()
+    gate.set()
+    svc = serve.ServingService(_held_predictor(model, gate, fail=True),
+                               window_ms=50.0)
+    try:
+        futs = [svc.submit(x[i]) for i in range(3)]
+        for f in futs:
+            with pytest.raises(RuntimeError, match="planted"):
+                f.result(timeout=30)
+    finally:
+        svc.close(timeout=30)
+    s = svc.stats
+    assert s["n_failed_requests"] == 3
+    assert s["n_requests"] == 0 and s["n_batches"] == 0
+
+
+def test_service_spans_nest_on_the_batcher_thread(ovo_problem, tmp_path):
+    """A profiler trace on the CPU holds ``serve.batch`` with its stages
+    nested in it, on the batcher's thread and no other."""
+    import glob
+    import jax
+    from jax.profiler import ProfileData
+    x, _, model = ovo_problem
+    svc = serve.ServingService(serve.pack(model), engine="chunked",
+                               window_ms=2.0)
+    try:
+        svc.predict(x[:4])                 # warm before the trace
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            futs = [svc.submit(x[i:i + 2], op=("predict", "values")[i % 2])
+                    for i in range(10)]
+            for f in futs:
+                f.result(timeout=30)
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        svc.close(timeout=30)
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                        recursive=True)
+    lines = [[(e.name, e.start_ns, e.start_ns + e.duration_ns,
+               {k: v for k, v in e.stats}) for e in ln.events
+              if e.name.startswith("serve.")]
+             for pl in ProfileData.from_file(path).planes
+             if pl.name.startswith("/host:") for ln in pl.lines]
+    lines = [ln for ln in lines if ln]
+    # one thread holds every serve.* span: the batcher's
+    assert len(lines) == 1
+    (events,) = lines
+    batches = [e for e in events if e[0] == "serve.batch"]
+    assert batches
+    assert all({"batch", "requests", "rows", "full"} <= set(b[3])
+               for b in batches)
+    assert sum(b[3]["requests"] for b in batches) == 10
+    assert sum(b[3]["rows"] for b in batches) == 20
+    inner = {}
+    for name, s, e, _ in events:
+        if name == "serve.batch":
+            continue
+        # every stage lies inside one batch
+        assert any(bs <= s and e <= be for _, bs, be, _ in batches), name
+        inner[name] = inner.get(name, 0) + 1
+    assert {"serve.collect", "serve.merge", "serve.decide", "serve.decode",
+            "serve.scatter", "serve.upload", "serve.launch",
+            "serve.fetch"} <= set(inner)
+    decides = [(s, e) for n, s, e, _ in events if n == "serve.decide"]
+    for name in ("serve.upload", "serve.launch", "serve.fetch"):
+        for n, s, e, _ in events:
+            if n == name:
+                assert any(ds <= s and e <= de for ds, de in decides)

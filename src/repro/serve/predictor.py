@@ -18,7 +18,11 @@ in Python — fine for evaluating a fit, hopeless under request traffic.
 * request batches are padding-bucketed: each micro-batch is zero-padded
   up to the next power of two (capped at ``max_batch``; longer requests
   stream in ``max_batch`` slices), so arbitrary request sizes reuse a
-  small warm set of compiled programs instead of recompiling per shape.
+  small warm set of compiled programs instead of recompiling per shape;
+* multiclass labels (OvO and OvR) decode through ONE jitted vote/argmax
+  program per pow2 decode width, the model's credit table baked in as
+  constants: one upload of the stacked decisions, one launch, one
+  fetch of the class indices.
 
 Padded test rows are sliced off before results leave the predictor, and
 padded SV rows carry ``coef == 0``, so padding never changes a served
@@ -88,7 +92,8 @@ class Predictor:
 
     # the served-row counter and program ledger are mutated by every
     # concurrent decision_values caller (enforced by analysis rule R004)
-    _GUARDED_BY = {"n_requests": "_lock", "_program_sigs": "_lock"}
+    _GUARDED_BY = {"n_requests": "_lock", "_program_sigs": "_lock",
+                   "_decode_widths": "_lock"}
 
     def __init__(self, model: PackedModel, *,
                  engine: str | KE.EngineConfig = "auto",
@@ -138,11 +143,28 @@ class Predictor:
         # one jitted callable; XLA caches one executable per distinct
         # (bucket shape, batch bucket) argument signature
         self._decide = jax.jit(self._decide_stack)
+        # multiclass label decode: the vote/argmax over the stacked
+        # decisions as one jitted program, the model's pairs and one-hot
+        # credit tables closed over as constants; XLA keeps one
+        # executable per (pow2) decode width. Binary and SVR decode on
+        # the host and need none.
+        self._decode_labels = None
+        if model.kind == "svc" and model.strategy != "binary":
+            pairs, n_classes = np.asarray(model.pairs), model.n_classes
+            strategy, decision = model.strategy, model.decision
+
+            def decode_labels(df):
+                return MC.decide_from_pairs(df, pairs, n_classes, strategy,
+                                            decision)
+
+            self._decode_labels = jax.jit(decode_labels)
         self.n_requests = 0  # rows served (warmup excluded)
         # predictor-owned ledger of distinct (bank signature, batch
         # bucket) program shapes — what n_programs reports; jax's
         # private jit cache introspection moved across versions
         self._program_sigs: set = set()
+        # decode widths served so far — one compiled decode program each
+        self._decode_widths: set = set()
         self._lock = threading.Lock()
 
     # ---------------------------------------------------------- programs
@@ -173,6 +195,15 @@ class Predictor:
         with self._lock:
             return len(self._program_sigs)
 
+    @property
+    def n_decode_programs(self) -> int:
+        """Compiled multiclass decode-program count: distinct pow2
+        decode widths served so far (0 for binary and SVR models, which
+        decode on the host). Counted apart from ``n_programs``, which
+        counts decide programs only."""
+        with self._lock:
+            return len(self._decode_widths)
+
     def _batch_bucket(self, t: int) -> int:
         return min(self.max_batch, 1 << (max(t, 1) - 1).bit_length())
 
@@ -185,7 +216,7 @@ class Predictor:
         d = self.model.n_features
         for t in batch_sizes:
             # predict() runs decision_values + decode, warming both the
-            # decide program and the vote/argmax ops at this bucket
+            # decide program and the decode program at this bucket
             self.predict(np.zeros((int(t), d), np.float32))
         # subtract exactly the synthetic rows rather than restoring a
         # pre-warmup snapshot: concurrent real requests served DURING
@@ -248,7 +279,9 @@ class Predictor:
 
         op: "values" (the stacked df, unchanged), "decision_function"
         (margins, sklearn orientation) or "predict" (labels / SVR
-        values)."""
+        values). Multiclass labels come from the compiled decode program
+        at ``df``'s pow2 width; binary labels and SVR values are read on
+        the host."""
         m = self.model
         if op == "values":
             return df
@@ -261,10 +294,9 @@ class Predictor:
             return df[0]
         if m.strategy == "binary":
             return m.classes[(df[0] > 0).astype(np.int64)]
-        # pad the vote/argmax decode onto the same pow2 ladder as the
-        # decide programs: its eager jnp ops compile per distinct width,
-        # so decoding at the raw width would grow the compile cache one
-        # entry per odd request size (a multi-hundred-ms stall apiece
+        # pad onto the pow2 ladder: the compiled decode program is keyed
+        # on width, so decoding at the raw width would compile one
+        # program per odd request size (a multi-hundred-ms stall apiece
         # under open-loop traffic). Padded columns (df == 0) are decoded
         # and discarded — the decision is columnwise.
         nt = df.shape[1]
@@ -273,9 +305,11 @@ class Predictor:
             dfp = np.zeros((df.shape[0], bucket), np.float32)
             dfp[:, :nt] = df
             df = dfp
-        idx = MC.decide_from_pairs(jnp.asarray(df), m.pairs, m.n_classes,
-                                   m.strategy, m.decision)
-        return m.classes[np.asarray(idx)[:nt]]
+        # one upload, one launch, one fetch of the (bucket,) indices
+        idx = np.asarray(self._decode_labels(df))
+        with self._lock:
+            self._decode_widths.add(bucket)
+        return m.classes[idx[:nt]]
 
     def decision_function(self, xt: np.ndarray) -> np.ndarray:
         """Margins in the training-side convention: (nt,) for binary

@@ -117,6 +117,95 @@ def test_micro_batch_slicing_matches_single_shot(prob, request):
         sliced.decision_values(xt), whole.decision_values(xt), nulp=4)
 
 
+# ---------------------------------------------------------------- decode
+def _decode_pack(strategy: str, decision: str, m: int = 9):
+    """A multiclass pack with Pavia's 9 classes and no support vectors:
+    the decode reads only the credit table, not the banks."""
+    if strategy == "ovo":
+        pairs = np.array([(i, j) for i in range(m) for j in range(i + 1, m)])
+    else:
+        pairs = np.stack([np.arange(m), -np.ones(m, np.int64)], axis=1)
+    n = len(pairs)
+    bank = serve.TaskBucket(task_ids=np.arange(n),
+                            sv_x=np.zeros((n, 0, 3), np.float32),
+                            sv_coef=np.zeros((n, 0), np.float32),
+                            b=np.zeros(n, np.float32),
+                            sv_counts=np.zeros(n, np.int64))
+    return serve.PackedModel(
+        kind="svc", kernel=K.KernelParams(name="rbf", gamma=1.0),
+        n_features=3, n_tasks=n, buckets=(bank,), strategy=strategy,
+        decision=decision, classes=np.arange(m), pairs=pairs)
+
+
+def _tied_ovo_df(pairs, m, nt, rng):
+    """(C, nt) OvO decisions; every even column is a forced vote tie:
+    three classes beat every other class and each other in a cycle, so
+    they lead on equal votes and only their (distinct) margins decide."""
+    wins = rng.random((nt, m, m)) < 0.5     # read at (p, q) only
+    for j in range(0, nt, 2):
+        a, b, c = rng.choice(m, 3, replace=False)
+        for w in (a, b, c):
+            wins[j, w, :] = True
+            wins[j, :, w] = False
+        for w, l in ((a, b), (b, c), (c, a)):
+            wins[j, w, l], wins[j, l, w] = True, False
+    mag = rng.uniform(0.05, 3.0, (len(pairs), nt))
+    sign = np.where(wins[:, pairs[:, 0], pairs[:, 1]].T, 1.0, -1.0)
+    return (sign * mag).astype(np.float32)
+
+
+def _decode_f64(df, pairs, m, strategy, decision):
+    """Float64 reference decode, written like ``bench.reference.vote``:
+    OvR argmax; OvO summed tanh margins, or votes with the margin as the
+    tie-break among the leaders and the lowest class index last."""
+    df = np.asarray(df, np.float64)
+    if strategy == "ovr":
+        return np.argmax(df, axis=0), 0
+    votes = np.zeros((df.shape[1], m))
+    margin = np.zeros((df.shape[1], m))
+    for t, (p, q) in enumerate(pairs):
+        pos = df[t] > 0
+        votes[pos, p] += 1
+        votes[~pos, q] += 1
+        margin[:, p] += np.tanh(df[t])
+        margin[:, q] -= np.tanh(df[t])
+    if decision == "margin":
+        return np.argmax(margin, axis=1), 0
+    lead = votes >= votes.max(1, keepdims=True) - 0.5
+    return np.argmax(np.where(lead, margin, -np.inf), axis=1), \
+        int((lead.sum(1) > 1).sum())
+
+
+@pytest.mark.parametrize("strategy, decision", [("ovo", "vote"),
+                                                ("ovo", "margin"),
+                                                ("ovr", "vote")])
+@pytest.mark.parametrize("nt", [1, 17, 1065, 2048])
+def test_compiled_decode_matches_eager_and_f64_votes(strategy, decision,
+                                                     nt):
+    """The jitted decode program gives the labels of the eager
+    ``MC.decide_from_pairs`` and of a float64 vote, at padded (1, 17,
+    1065) and exact (2048) widths, with forced vote ties for OvO."""
+    import jax.numpy as jnp
+    from repro.core import multiclass as MC
+    packed = _decode_pack(strategy, decision)
+    pairs, m = packed.pairs, packed.n_classes
+    rng = np.random.default_rng(1000 + nt)
+    if strategy == "ovo":
+        df = _tied_ovo_df(pairs, m, nt, rng)
+    else:
+        df = rng.standard_normal((m, nt)).astype(np.float32)
+    pred = serve.Predictor(packed, engine="chunked")
+    got = pred.decode(df, "predict")
+    eager = np.asarray(MC.decide_from_pairs(jnp.asarray(df), pairs, m,
+                                            strategy, decision))
+    want, n_ties = _decode_f64(df, pairs, m, strategy, decision)
+    np.testing.assert_array_equal(got, eager)
+    np.testing.assert_array_equal(got, want)
+    if (strategy, decision) == ("ovo", "vote"):
+        assert n_ties >= (nt + 1) // 2
+    assert pred.n_decode_programs == 1
+
+
 # ------------------------------------------------------------- artifacts
 def test_artifact_roundtrip_multiclass(ovo_problem, tmp_path):
     x, y, model = ovo_problem
@@ -276,10 +365,34 @@ def test_predictor_replay_within_compile_budget(ovo_problem,
     x, _, model = ovo_problem
     pred = serve.Predictor(serve.pack(model), engine="chunked")
     pred.warmup(batch_sizes=(32,))
+    assert pred.n_decode_programs == 1
     with compile_guard(budget=0, note="warm-bucket replay") as g:
-        for nt in (17, 21, 25, 29, 32):
+        for nt in range(17, 33):
             pred.predict(x[:nt])
     assert g.count == 0 and pred.n_programs == len(model._serving_buckets)
+    assert pred.n_decode_programs == 1
+
+
+@pytest.mark.parametrize("prob, n_widths", [("ovo_problem", 3),
+                                            ("ovr_problem", 3),
+                                            ("binary_problem", 0),
+                                            ("svr_problem", 0)])
+def test_decode_programs_one_per_warmed_width(prob, n_widths, request,
+                                              compile_guard):
+    """Warm-up compiles one decode program per multiclass ladder width
+    (binary and SVR decode on the host: none), counted apart from the
+    decide programs; replays inside the warm widths compile nothing."""
+    x, _, model = request.getfixturevalue(prob)
+    pred = serve.Predictor(serve.pack(model), engine="chunked")
+    pred.warmup(batch_sizes=(1, 5, 32))
+    assert pred.n_decode_programs == n_widths
+    n_programs = pred.n_programs
+    with compile_guard(budget=0, note="warm decode replay") as g:
+        for nt in (1, 5, 6, 7, 8, 17, 20, 32):
+            pred.predict(x[:nt])
+    assert g.count == 0
+    assert pred.n_decode_programs == n_widths
+    assert pred.n_programs == n_programs
 
 
 def test_max_batch_rounds_down_to_pow2(binary_problem):

@@ -22,7 +22,12 @@ in Python — fine for evaluating a fit, hopeless under request traffic.
 * multiclass labels (OvO and OvR) decode through ONE jitted vote/argmax
   program per pow2 decode width, the model's credit table baked in as
   constants: one upload of the stacked decisions, one launch, one
-  fetch of the class indices.
+  fetch of the class indices;
+* ``work`` counts what the calls launched and moved, from the shapes
+  they ran at: ``decide_evals``, the kernel values of the decide
+  programs (padded rows x bank width x tasks of every launch), and
+  ``transfer_bytes``, every byte the decide and the decode uploaded or
+  fetched.
 
 Padded test rows are sliced off before results leave the predictor, and
 padded SV rows carry ``coef == 0``, so padding never changes a served
@@ -93,7 +98,7 @@ class Predictor:
     # the served-row counter and program ledger are mutated by every
     # concurrent decision_values caller (enforced by analysis rule R004)
     _GUARDED_BY = {"n_requests": "_lock", "_program_sigs": "_lock",
-                   "_decode_widths": "_lock"}
+                   "_decode_widths": "_lock", "_work": "_lock"}
 
     def __init__(self, model: PackedModel, *,
                  engine: str | KE.EngineConfig = "auto",
@@ -165,6 +170,7 @@ class Predictor:
         self._program_sigs: set = set()
         # decode widths served so far — one compiled decode program each
         self._decode_widths: set = set()
+        self._work = {"decide_evals": 0, "transfer_bytes": 0}
         self._lock = threading.Lock()
 
     # ---------------------------------------------------------- programs
@@ -204,6 +210,13 @@ class Predictor:
         with self._lock:
             return len(self._decode_widths)
 
+    @property
+    def work(self) -> dict:
+        """Cumulative ``decide_evals`` and ``transfer_bytes`` of every
+        call so far, warmup included (see module docstring)."""
+        with self._lock:
+            return dict(self._work)
+
     def _batch_bucket(self, t: int) -> int:
         return min(self.max_batch, 1 << (max(t, 1) - 1).bit_length())
 
@@ -236,6 +249,7 @@ class Predictor:
         nt = xt.shape[0]
         out = np.empty((self.model.n_tasks, nt), np.float32)
         sigs = []
+        evals = moved = 0
         # host spans per slice (and bank): the pad and upload, the jitted
         # call, and the fetch that waits for the device and copies back
         span = jax.profiler.TraceAnnotation
@@ -246,28 +260,37 @@ class Predictor:
                 zp = np.zeros((bucket, xt.shape[1]), np.float32)
                 zp[:stop - start] = xt[start:stop]
                 zj = jnp.asarray(zp)
+            moved += zp.nbytes
             if self.model.feature_map is not None:
                 a, fb = self._fm_arrays
                 w, lb = self._linear
                 with span("serve.launch"):
                     df = self._decide_lowrank(a, fb, w, lb, zj)
                 with span("serve.fetch"):
-                    out[:, start:stop] = np.asarray(df)[:, :stop - start]
+                    df = np.asarray(df)
+                    out[:, start:stop] = df[:, :stop - start]
+                moved += df.nbytes
                 sigs.append(("lowrank", bucket))
                 continue
             for sv_x, sv_coef, b, task_ids in self._banks:
                 if sv_x.shape[1] == 0:  # empty-SV bank: constant bias
-                    out[task_ids, start:stop] = np.asarray(b)[:, None]
+                    bias = np.asarray(b)
+                    out[task_ids, start:stop] = bias[:, None]
+                    moved += bias.nbytes
                     continue
                 with span("serve.launch"):
                     df = self._decide(sv_x, sv_coef, b, zj)
                 with span("serve.fetch"):
-                    out[task_ids, start:stop] = np.asarray(
-                        df)[:, :stop - start]
+                    df = np.asarray(df)
+                    out[task_ids, start:stop] = df[:, :stop - start]
+                evals += bucket * sv_x.shape[0] * sv_x.shape[1]
+                moved += df.nbytes
                 sigs.append((sv_x.shape, str(sv_x.dtype), bucket))
         with self._lock:
             self._program_sigs.update(sigs)
             self.n_requests += nt
+            self._work["decide_evals"] += evals
+            self._work["transfer_bytes"] += moved
         return out
 
     def decode(self, df: np.ndarray, op: str = "predict") -> np.ndarray:
@@ -309,6 +332,7 @@ class Predictor:
         idx = np.asarray(self._decode_labels(df))
         with self._lock:
             self._decode_widths.add(bucket)
+            self._work["transfer_bytes"] += df.nbytes + idx.nbytes
         return m.classes[idx[:nt]]
 
     def decision_function(self, xt: np.ndarray) -> np.ndarray:

@@ -40,7 +40,10 @@ batches served (``rows_per_batch``), window against full flushes,
 ``queue_wait_s`` (summed from each ``submit`` to the batcher taking the
 request), and the batcher's host seconds per stage: ``batch_s`` in all,
 ``collect_s``, ``merge_s``, ``decide_s``, ``decode_s``, ``scatter_s``,
-and ``batcher_cpu_s``, its thread's CPU time inside batches. Each stage
+and ``batcher_cpu_s``, its thread's CPU time inside batches; and what
+the predictors' ``work`` counted for the batches: ``decide_evals``
+(kernel values launched) and ``transfer_bytes`` (bytes uploaded and
+fetched by decide and decode). Each stage
 is also a ``jax.profiler.TraceAnnotation`` (``serve.trace``), so a
 profiler trace shows, on the device's clock, ``serve.batch`` (metadata
 ``batch``, ``requests``, ``rows``, ``full``) and nested in it
@@ -113,7 +116,8 @@ class ServingService:
                        "queue_wait_s": 0.0, "batch_s": 0.0,
                        "batcher_cpu_s": 0.0, "collect_s": 0.0,
                        "merge_s": 0.0, "decide_s": 0.0, "decode_s": 0.0,
-                       "scatter_s": 0.0}
+                       "scatter_s": 0.0, "decide_evals": 0,
+                       "transfer_bytes": 0}
         self._worker = threading.Thread(target=self._run,
                                         name="repro-serving-batcher",
                                         daemon=True)
@@ -276,6 +280,7 @@ class ServingService:
         for name, reqs in by_model.items():
             try:
                 pred = self._predictor(name)
+                work = pred.work
                 with stages("serve.merge", "merge_s"):
                     xcat = (reqs[0].x if len(reqs) == 1
                             else np.concatenate([r.x for r in reqs], axis=0))
@@ -286,6 +291,8 @@ class ServingService:
                 with stages("serve.decode", "decode_s"):
                     decoded = {op: pred.decode(df, op)
                                for op in {r.op for r in reqs}}
+                for k, v in pred.work.items():
+                    stages.add(k, v - work[k])
             except Exception as e:                 # noqa: BLE001
                 stages.add("n_failed_requests", len(reqs))
                 for r in reqs:

@@ -366,14 +366,18 @@ def test_quantize_helper_and_validation(binary_problem):
 def test_predictor_concurrent_decision_values(ovo_problem):
     """Concurrent callers must not corrupt n_requests nor interleave
     partially-written outputs: every thread's values match the serial
-    reference exactly, and the served-row counter is the exact total."""
+    reference exactly, and the served-row and work counters are the
+    exact totals."""
     x, _, model = ovo_problem
     pred = serve.Predictor(serve.pack(model), engine="chunked")
     pred.warmup(batch_sizes=(4, 16))
     slices = [(i % 40, 4 + (i % 3) * 12) for i in range(48)]
-    want = {(s, n): pred.decision_values(x[s:s + n]) for s, n in
-            set(slices)}
-    served0 = pred.n_requests
+    want, work = {}, {}
+    for s, n in set(slices):
+        before = pred.work
+        want[(s, n)] = pred.decision_values(x[s:s + n])
+        work[(s, n)] = {k: v - before[k] for k, v in pred.work.items()}
+    served0, work0 = pred.n_requests, pred.work
     barrier = threading.Barrier(8)
     errors = []
 
@@ -395,6 +399,8 @@ def test_predictor_concurrent_decision_values(ovo_problem):
         t.join(timeout=120)
     assert not errors, errors
     assert pred.n_requests == served0 + sum(n for _, n in slices)
+    for k, v in pred.work.items():
+        assert v == work0[k] + sum(work[sl][k] for sl in slices), k
 
 
 def test_predictor_decode_validates_op(binary_problem):
@@ -682,3 +688,40 @@ def test_service_spans_nest_on_the_batcher_thread(ovo_problem, tmp_path):
         for n, s, e, _ in events:
             if n == name:
                 assert any(ds <= s and e <= de for ds, de in decides)
+
+
+def test_predictor_work_counts_padded_kernel_values_and_bytes(ovo_problem):
+    """``Predictor.work`` counts what the calls launched: per slice and
+    bank the padded rows times the bank's width times its tasks, and
+    every byte uploaded or fetched by decide and decode."""
+    x, _, model = ovo_problem
+    packed = serve.pack(model)
+    pred = serve.Predictor(packed, engine="chunked", max_batch=8)
+    d, n_tasks = packed.n_features, packed.n_tasks
+    before = pred.work
+    pred.predict(x[:11])          # two slices: 8 rows and 3 padded to 4
+    got = {k: v - before[k] for k, v in pred.work.items()}
+    per_row = sum(g.sv_x.shape[0] * g.sv_x.shape[1] for g in packed.buckets)
+    assert got["decide_evals"] == (8 + 4) * per_row
+    fetched = sum(4 * g.sv_x.shape[0] * (8 + 4) for g in packed.buckets)
+    decode = 4 * n_tasks * 16 + 4 * 16   # df up at width 16, labels back
+    assert got["transfer_bytes"] == 4 * (8 + 4) * d + fetched + decode
+    before = pred.work
+    pred.decode(pred.decision_values(x[:3]), "values")
+    got = {k: v - before[k] for k, v in pred.work.items()}
+    assert got["transfer_bytes"] == 4 * 4 * d + sum(
+        4 * g.sv_x.shape[0] * 4 for g in packed.buckets)
+
+
+def test_service_stats_fold_the_predictors_work(ovo_problem):
+    x, _, model = ovo_problem
+    pred = serve.Predictor(serve.pack(model), engine="chunked").warmup(
+        (1, 2, 4, 8, 16, 32))
+    before = pred.work
+    with serve.ServingService(pred, window_ms=2.0) as svc:
+        futs = [svc.submit(x[i:i + 1 + i % 3]) for i in range(12)]
+        for f in futs:
+            f.result(timeout=30)
+    s = svc.stats
+    for k, v in pred.work.items():
+        assert s[k] == v - before[k] > 0, k

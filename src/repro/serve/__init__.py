@@ -23,7 +23,8 @@ from repro.serve.artifact import (LowRankMap, PackedModel,  # noqa: F401
                                   SCHEMA_VERSION_CLASSIC,
                                   SCHEMA_VERSION_QUANT, SCHEMA_VERSIONS,
                                   SV_DTYPES, load, pack, quantize, save)
-from repro.serve.predictor import Predictor, serving_config  # noqa: F401
+from repro.serve.predictor import (Decisions, Predictor,  # noqa: F401
+                                   serving_config)
 from repro.serve.registry import ModelRegistry  # noqa: F401
 from repro.serve import reference  # noqa: F401
 from repro.serve.service import ServingService  # noqa: F401

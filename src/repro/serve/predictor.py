@@ -19,15 +19,31 @@ in Python — fine for evaluating a fit, hopeless under request traffic.
   up to the next power of two (capped at ``max_batch``; longer requests
   stream in ``max_batch`` slices), so arbitrary request sizes reuse a
   small warm set of compiled programs instead of recompiling per shape;
-* multiclass labels (OvO and OvR) decode through ONE jitted vote/argmax
-  program per pow2 decode width, the model's credit table baked in as
-  constants: one upload of the stacked decisions, one launch, one
+* ``decide`` leaves a batch's decisions on the device
+  (``Decisions``): per slice, every bank's decide program is launched
+  and then ONE assembly program per slice width puts the bank outputs
+  in task order (width-0 banks contribute their bias), runs the
+  multiclass vote (OvO and OvR; the model's pairs and credit tables
+  baked in as constants) and splits the batch's rows at the pow2
+  boundaries 0, 1, 2, 4, ..., bucket, each piece a (rows, n_tasks)
+  block that the TPU stores densely. Nothing is fetched until a
+  caller asks: ``decode(dec, "predict")`` fetches the int32 class
+  indices of each slice, and ``decision_values(dec.head(n))`` fetches
+  the pow2 prefix of pieces that holds the first ``n`` rows. So a
+  batch that asks for labels only moves its rows up and its labels
+  down, and its decision matrix never crosses the link. Binary and
+  SVR models vote nowhere: their labels and values are read on the
+  host from the fetched decisions;
+* a host ``df`` (``decode(df, "predict")``) decodes through one jitted
+  vote program per pow2 width of ``df``: one upload, one launch, one
   fetch of the class indices;
 * ``work`` counts what the calls launched and moved, from the shapes
   they ran at: ``decide_evals``, the kernel values of the decide
-  programs (padded rows x bank width x tasks of every launch), and
-  ``transfer_bytes``, every byte the decide and the decode uploaded or
-  fetched.
+  programs (padded rows x bank width x tasks of every launch),
+  ``transfer_bytes``, every byte that crossed the link (rows up,
+  decision values and labels down, a host ``df`` up for its vote), and
+  ``resident_votes``, the slices whose labels were fetched while their
+  decision values never were.
 
 Padded test rows are sliced off before results leave the predictor, and
 padded SV rows carry ``coef == 0``, so padding never changes a served
@@ -41,8 +57,9 @@ cross-Gram contraction, so accumulation is always f32 regardless of how
 the bank is stored. fp32 packs are bit-identical to pre-quantization
 serving (the upcast is a no-op).
 
-``decision_values`` is thread-safe: concurrent callers each own their
-output buffer, jit dispatch is safe under concurrency, and the served-
+``decide``, ``decision_values`` and ``decode`` are thread-safe:
+concurrent callers each own their ``Decisions`` and output buffers, jit
+dispatch is safe under concurrency, and the served-
 row counter / compiled-program ledger are guarded by a lock — the
 dynamic-batching service (``serve.service``) and its submitters may
 share one predictor freely.
@@ -56,6 +73,9 @@ training-set size.
     pred = Predictor(serve.pack(clf), engine="pallas")
     pred.predict(Z)                   # class labels / SVR values
     pred.decision_function(Z)         # margins, sklearn orientation
+    dec = pred.decide(Z)              # decisions left on the device
+    pred.decision_values(dec.head(8)) # values of the first 8 rows only
+    pred.decode(dec, "predict")       # labels, voted on the device
 """
 from __future__ import annotations
 
@@ -71,6 +91,8 @@ from repro.core import kernels as K
 from repro.core import multiclass as MC
 from repro.kernels import ops
 from repro.serve.artifact import PackedModel
+
+_OPS = ("predict", "decision_function", "values")
 
 
 def serving_config(engine: str | KE.EngineConfig) -> KE.EngineConfig:
@@ -90,6 +112,44 @@ def serving_config(engine: str | KE.EngineConfig) -> KE.EngineConfig:
 
 def _pow2_floor(n: int) -> int:
     return 1 << (int(n).bit_length() - 1)
+
+
+@dataclasses.dataclass
+class _Slice:
+    """One ``max_batch`` slice of a ``Decisions``: its first row, its
+    row count, its class indices on the device (None where the model
+    votes nowhere), its (rows, n_tasks) decisions on the device split
+    at the pow2 row boundaries 0, 1, 2, 4, ..., bucket, and whether any
+    of them was fetched."""
+    start: int
+    rows: int
+    labels: object
+    pieces: tuple
+    fetched: bool = False
+
+
+class Decisions:
+    """The stacked decisions of one request batch, left on the device by
+    ``Predictor.decide``. ``head(n)`` is a view of its first ``n`` rows
+    (the slices are shared), which ``decision_values`` fetches as the
+    pow2 prefix of each slice's pieces that holds them."""
+
+    __slots__ = ("slices", "n_rows")
+
+    def __init__(self, slices: tuple, n_rows: int):
+        self.slices, self.n_rows = slices, n_rows
+
+    def head(self, n: int) -> "Decisions":
+        return Decisions(self.slices, min(n, self.n_rows))
+
+    def _spans(self):
+        """(slice, rows of it in this view) for every slice the view
+        reaches."""
+        for s in self.slices:
+            rows = min(s.rows, self.n_rows - s.start)
+            if rows <= 0:
+                break
+            yield s, rows
 
 
 class Predictor:
@@ -121,12 +181,20 @@ class Predictor:
                 f"engine='pallas' serves the rbf and linear kernels; "
                 f"this model's kernel is {model.kernel.name!r} — serve it "
                 f"with engine='chunked'")
-        # SV banks move to device once and stay resident; task_ids stay
-        # host-side (they only scatter results back into request order)
+        # SV banks move to device once and stay resident; the task ids
+        # become one row permutation the assembly program closes over
         self._banks = tuple(
-            (jnp.asarray(g.sv_x), jnp.asarray(g.sv_coef),
-             jnp.asarray(g.b), np.asarray(g.task_ids))
+            (jnp.asarray(g.sv_x), jnp.asarray(g.sv_coef), jnp.asarray(g.b))
             for g in model.buckets)
+        # row t of the task-ordered matrix is row _order[t] of the bank
+        # outputs stacked in bank order; None where that is the identity
+        # (one bank in task order, or a low-rank pack)
+        self._order = None
+        if model.buckets:
+            order = np.argsort(np.concatenate(
+                [np.asarray(g.task_ids) for g in model.buckets]))
+            if (order != np.arange(len(order))).any():
+                self._order = order
         if model.feature_map is not None:
             # low-rank pack: resident map arrays + stacked linear
             # weights; one jitted transform+matmul program per batch
@@ -149,11 +217,12 @@ class Predictor:
         # (bucket shape, batch bucket) argument signature
         self._decide = jax.jit(self._decide_stack)
         # multiclass label decode: the vote/argmax over the stacked
-        # decisions as one jitted program, the model's pairs and one-hot
-        # credit tables closed over as constants; XLA keeps one
-        # executable per (pow2) decode width. Binary and SVR decode on
-        # the host and need none.
-        self._decode_labels = None
+        # decisions, the model's pairs and one-hot credit tables closed
+        # over as constants. It runs inside the assembly program on the
+        # device, and as a jitted program of its own for a host df; XLA
+        # keeps one executable of each per width. Binary and SVR decode
+        # on the host and need neither.
+        self._vote = self._decode_labels = None
         if model.kind == "svc" and model.strategy != "binary":
             pairs, n_classes = np.asarray(model.pairs), model.n_classes
             strategy, decision = model.strategy, model.decision
@@ -162,15 +231,20 @@ class Predictor:
                 return MC.decide_from_pairs(df, pairs, n_classes, strategy,
                                             decision)
 
+            self._vote = decode_labels
             self._decode_labels = jax.jit(decode_labels)
+        # one assembly (+ vote) executable per slice width
+        self._assemble = jax.jit(self._assemble_slice, static_argnums=1)
         self.n_requests = 0  # rows served (warmup excluded)
         # predictor-owned ledger of distinct (bank signature, batch
         # bucket) program shapes — what n_programs reports; jax's
         # private jit cache introspection moved across versions
         self._program_sigs: set = set()
-        # decode widths served so far — one compiled decode program each
+        # vote widths served so far — one compiled vote program each,
+        # keyed ("device", slice width) or ("host", pow2 width of a df)
         self._decode_widths: set = set()
-        self._work = {"decide_evals": 0, "transfer_bytes": 0}
+        self._work = {"decide_evals": 0, "transfer_bytes": 0,
+                      "resident_votes": 0}
         self._lock = threading.Lock()
 
     # ---------------------------------------------------------- programs
@@ -192,6 +266,25 @@ class Predictor:
 
         return jax.vmap(one)(sv_x, sv_coef, b)
 
+    def _assemble_slice(self, parts, bucket):
+        """One slice's bank outputs ((T_g, bucket) decisions, or (T_g,)
+        biases of width-0 banks) -> its class indices (None where the
+        model votes nowhere) and its decisions in task order, transposed
+        to (bucket, n_tasks) — a few rows of it are then a dense block
+        in the device's tiled layout, where a few columns of
+        (n_tasks, bucket) would pad each to a full tile — and split at
+        the pow2 row boundaries 0, 1, 2, 4, ..., bucket. A copy of the
+        values: the decide programs' outputs are served bit for bit."""
+        cols = [p if p.ndim == 2 else jnp.broadcast_to(
+            p.astype(jnp.float32)[:, None], (p.shape[0], bucket))
+            for p in parts]
+        df = cols[0] if len(cols) == 1 else jnp.concatenate(cols)
+        if self._order is not None:
+            df = df[self._order]
+        labels = None if self._vote is None else self._vote(df)
+        edges = [0] + [1 << i for i in range(bucket.bit_length())]
+        return labels, tuple(df.T[a:b] for a, b in zip(edges, edges[1:]))
+
     @property
     def n_programs(self) -> int:
         """Compiled decide-program count: distinct (bank shape/dtype,
@@ -203,17 +296,19 @@ class Predictor:
 
     @property
     def n_decode_programs(self) -> int:
-        """Compiled multiclass decode-program count: distinct pow2
-        decode widths served so far (0 for binary and SVR models, which
-        decode on the host). Counted apart from ``n_programs``, which
-        counts decide programs only."""
+        """Compiled multiclass vote-program count: the slice widths
+        ``decide`` assembled and voted on the device, plus the pow2
+        widths of the host ``df`` that ``decode`` voted on (0 for binary
+        and SVR models, which vote nowhere). Counted apart from
+        ``n_programs``, which counts decide programs only."""
         with self._lock:
             return len(self._decode_widths)
 
     @property
     def work(self) -> dict:
-        """Cumulative ``decide_evals`` and ``transfer_bytes`` of every
-        call so far, warmup included (see module docstring)."""
+        """Cumulative ``decide_evals``, ``transfer_bytes`` and
+        ``resident_votes`` of every call so far, warmup included (see
+        module docstring)."""
         with self._lock:
             return dict(self._work)
 
@@ -221,15 +316,17 @@ class Predictor:
         return min(self.max_batch, 1 << (max(t, 1) - 1).bit_length())
 
     def warmup(self, batch_sizes=(1,)) -> "Predictor":
-        """Pre-compile the decide programs AND the decode (label) path
-        for the given request sizes.
+        """Pre-compile the decide programs AND the assembly and vote
+        program for the given request sizes. A pow2 prefix of decision
+        values needs no program of its own: it is a run of the pieces
+        the assembly program already returns.
 
         Warmup rows are synthetic and do NOT count toward
         ``n_requests`` (the served-row counter)."""
         d = self.model.n_features
         for t in batch_sizes:
-            # predict() runs decision_values + decode, warming both the
-            # decide program and the decode program at this bucket
+            # predict() runs decide + decode, warming the decide
+            # programs and the assembly program at this bucket
             self.predict(np.zeros((int(t), d), np.float32))
         # subtract exactly the synthetic rows rather than restoring a
         # pre-warmup snapshot: concurrent real requests served DURING
@@ -239,19 +336,24 @@ class Predictor:
         return self
 
     # ------------------------------------------------------------ serving
-    def decision_values(self, xt: np.ndarray) -> np.ndarray:
-        """(n_tasks, nt) stacked binary decision values."""
+    def needs_values(self, op: str) -> bool:
+        """Whether ``op``'s answer is read from fetched decision values:
+        every op but a multiclass "predict", which the device votes."""
+        return op != "predict" or self._vote is None
+
+    def decide(self, xt: np.ndarray) -> Decisions:
+        """Upload the (n, d) request batch in padded slices and launch
+        every bank's decide program and the slice's assembly program on
+        each; nothing is fetched (see ``Decisions``)."""
         xt = np.asarray(xt, np.float32)
         if xt.ndim != 2 or xt.shape[1] != self.model.n_features:
             raise ValueError(
                 f"expected (n, {self.model.n_features}) request batch, "
                 f"got shape {xt.shape}")
         nt = xt.shape[0]
-        out = np.empty((self.model.n_tasks, nt), np.float32)
-        sigs = []
+        slices, sigs, widths = [], [], []
         evals = moved = 0
-        # host spans per slice (and bank): the pad and upload, the jitted
-        # call, and the fetch that waits for the device and copies back
+        # host spans per slice: the pad and upload, then the launches
         span = jax.profiler.TraceAnnotation
         for start in range(0, nt, self.max_batch):
             stop = min(start + self.max_batch, nt)
@@ -261,58 +363,95 @@ class Predictor:
                 zp[:stop - start] = xt[start:stop]
                 zj = jnp.asarray(zp)
             moved += zp.nbytes
-            if self.model.feature_map is not None:
-                a, fb = self._fm_arrays
-                w, lb = self._linear
-                with span("serve.launch"):
-                    df = self._decide_lowrank(a, fb, w, lb, zj)
-                with span("serve.fetch"):
-                    df = np.asarray(df)
-                    out[:, start:stop] = df[:, :stop - start]
-                moved += df.nbytes
-                sigs.append(("lowrank", bucket))
-                continue
-            for sv_x, sv_coef, b, task_ids in self._banks:
-                if sv_x.shape[1] == 0:  # empty-SV bank: constant bias
-                    bias = np.asarray(b)
-                    out[task_ids, start:stop] = bias[:, None]
-                    moved += bias.nbytes
-                    continue
-                with span("serve.launch"):
-                    df = self._decide(sv_x, sv_coef, b, zj)
-                with span("serve.fetch"):
-                    df = np.asarray(df)
-                    out[task_ids, start:stop] = df[:, :stop - start]
-                evals += bucket * sv_x.shape[0] * sv_x.shape[1]
-                moved += df.nbytes
-                sigs.append((sv_x.shape, str(sv_x.dtype), bucket))
+            with span("serve.launch"):
+                if self.model.feature_map is not None:
+                    a, fb = self._fm_arrays
+                    w, lb = self._linear
+                    parts = [self._decide_lowrank(a, fb, w, lb, zj)]
+                    sigs.append(("lowrank", bucket))
+                else:
+                    parts = []
+                    for sv_x, sv_coef, b in self._banks:
+                        if sv_x.shape[1] == 0:  # empty-SV bank: its bias
+                            parts.append(b)
+                            continue
+                        parts.append(self._decide(sv_x, sv_coef, b, zj))
+                        evals += bucket * sv_x.shape[0] * sv_x.shape[1]
+                        sigs.append((sv_x.shape, str(sv_x.dtype), bucket))
+                labels, pieces = self._assemble(tuple(parts), bucket)
+            if labels is not None:
+                widths.append(("device", bucket))
+            slices.append(_Slice(start, stop - start, labels, pieces))
         with self._lock:
             self._program_sigs.update(sigs)
+            self._decode_widths.update(widths)
             self.n_requests += nt
             self._work["decide_evals"] += evals
             self._work["transfer_bytes"] += moved
+        return Decisions(tuple(slices), nt)
+
+    def decision_values(self, xt) -> np.ndarray:
+        """(n_tasks, nt) stacked binary decision values of a request
+        batch, or of the rows of a ``Decisions`` (``decide(x).head(k)``:
+        only the pow2 prefix of each slice that holds them crosses the
+        link)."""
+        dec = xt if isinstance(xt, Decisions) else self.decide(xt)
+        spans = list(dec._spans())
+        with jax.profiler.TraceAnnotation("serve.fetch"):
+            # pieces 0..k-1 hold rows [0, 2**(k-1)): the pow2 prefix
+            got = jax.device_get([s.pieces[:(rows - 1).bit_length() + 1]
+                                  for s, rows in spans])
+        out = np.empty((self.model.n_tasks, dec.n_rows), np.float32)
+        moved = 0
+        for (s, rows), pieces in zip(spans, got):
+            s.fetched = True
+            out[:, s.start:s.start + rows] = np.concatenate(
+                pieces)[:rows].T
+            moved += sum(p.nbytes for p in pieces)
+        with self._lock:
+            self._work["transfer_bytes"] += moved
         return out
 
-    def decode(self, df: np.ndarray, op: str = "predict") -> np.ndarray:
-        """Post-process stacked decision values ``df (n_tasks, nt)``
-        into the requested output — the per-model decode step the
-        dynamic-batching service shares across every request of a fused
-        batch (compute ``decision_values`` once, decode column slices
-        per request).
+    def _labels(self, dec: Decisions) -> np.ndarray:
+        """Class labels of a ``Decisions``, from the class indices the
+        assembly program voted on the device: one fetch per slice."""
+        spans = list(dec._spans())
+        with jax.profiler.TraceAnnotation("serve.fetch"):
+            idx = jax.device_get([s.labels for s, _ in spans])
+        with self._lock:
+            self._work["transfer_bytes"] += sum(i.nbytes for i in idx)
+            self._work["resident_votes"] += sum(
+                not s.fetched for s, _ in spans)
+        return self.model.classes[np.concatenate(
+            [i[:rows] for (_, rows), i in zip(spans, idx)]
+            or [np.zeros(0, np.int32)])]
+
+    def decode(self, df, op: str = "predict") -> np.ndarray:
+        """Post-process stacked decision values into the requested
+        output — the per-model decode step the dynamic-batching service
+        shares across every request of a fused batch (decide once,
+        decode column slices per request). ``df`` is a host
+        ``(n_tasks, nt)`` array or a ``Decisions``.
 
         op: "values" (the stacked df, unchanged), "decision_function"
         (margins, sklearn orientation) or "predict" (labels / SVR
-        values). Multiclass labels come from the compiled decode program
-        at ``df``'s pow2 width; binary labels and SVR values are read on
+        values). Multiclass labels of a ``Decisions`` are the indices
+        its assembly program voted on the device, and nothing else is
+        fetched; those of a host df come from the compiled vote program
+        at ``df``'s pow2 width. Binary labels and SVR values are read on
         the host."""
         m = self.model
+        if op not in _OPS:
+            raise ValueError(f"unknown decode op {op!r}; expected "
+                             "'predict', 'decision_function' or 'values'")
+        if isinstance(df, Decisions):
+            if not self.needs_values(op):
+                return self._labels(df)
+            df = self.decision_values(df)
         if op == "values":
             return df
         if op == "decision_function":
             return df[0] if m.strategy in ("binary", "svr") else df
-        if op != "predict":
-            raise ValueError(f"unknown decode op {op!r}; expected "
-                             "'predict', 'decision_function' or 'values'")
         if m.kind == "svr":
             return df[0]
         if m.strategy == "binary":
@@ -331,7 +470,7 @@ class Predictor:
         # one upload, one launch, one fetch of the (bucket,) indices
         idx = np.asarray(self._decode_labels(df))
         with self._lock:
-            self._decode_widths.add(bucket)
+            self._decode_widths.add(("host", bucket))
             self._work["transfer_bytes"] += df.nbytes + idx.nbytes
         return m.classes[idx[:nt]]
 
@@ -343,4 +482,4 @@ class Predictor:
 
     def predict(self, xt: np.ndarray) -> np.ndarray:
         """Class labels (SVC) or regression values (SVR)."""
-        return self.decode(self.decision_values(xt), "predict")
+        return self.decode(self.decide(xt), "predict")

@@ -13,8 +13,10 @@ as 1 row — so the service batches the queue:
   ``window_ms`` (measured from the FIRST request of the window) or
   until some model's collected rows reach its predictor's
   ``max_batch`` — whichever comes first — then flushes: per model, one
-  fused ``decision_values`` over the concatenated rows, one vectorized
-  decode, and the per-request slices scattered back through the
+  fused ``decide`` over the concatenated rows, the requests that read
+  decision values ordered first so that only a pow2 prefix of the
+  decisions is fetched, labels voted on the device, one vectorized
+  decode per op, and the per-request slices scattered back through the
   futures;
 * requests for different models share a window (the registry keeps
   their banks resident); an idle service burns no CPU (the worker
@@ -42,14 +44,16 @@ request), and the batcher's host seconds per stage: ``batch_s`` in all,
 ``collect_s``, ``merge_s``, ``decide_s``, ``decode_s``, ``scatter_s``,
 and ``batcher_cpu_s``, its thread's CPU time inside batches; and what
 the predictors' ``work`` counted for the batches: ``decide_evals``
-(kernel values launched) and ``transfer_bytes`` (bytes uploaded and
-fetched by decide and decode). Each stage
+(kernel values launched), ``transfer_bytes`` (bytes uploaded and
+fetched by decide and decode) and ``resident_votes`` (slices voted on
+the device whose decision values were never fetched). Each stage
 is also a ``jax.profiler.TraceAnnotation`` (``serve.trace``), so a
 profiler trace shows, on the device's clock, ``serve.batch`` (metadata
 ``batch``, ``requests``, ``rows``, ``full``) and nested in it
 ``serve.collect``, ``serve.merge``, ``serve.decide`` (the predictor's
-``serve.upload``, ``serve.launch``, ``serve.fetch`` inside),
-``serve.decode`` and ``serve.scatter``. The batcher's blocking wait for
+``serve.upload`` and ``serve.launch`` inside), ``serve.decode`` (the
+predictor's ``serve.fetch`` inside: the first fetch of a batch waits
+for the device) and ``serve.scatter``. The batcher's blocking wait for
 a first request lies outside every span.
 """
 from __future__ import annotations
@@ -63,11 +67,10 @@ from typing import NamedTuple, Optional, Union
 import numpy as np
 
 from repro.serve.artifact import PackedModel
-from repro.serve.predictor import Predictor, _pow2_floor
+from repro.serve.predictor import _OPS, Predictor, _pow2_floor
 from repro.serve.registry import ModelRegistry
 from repro.serve.trace import Stages
 
-_OPS = ("predict", "decision_function", "values")
 _SENTINEL = object()
 
 
@@ -117,7 +120,7 @@ class ServingService:
                        "batcher_cpu_s": 0.0, "collect_s": 0.0,
                        "merge_s": 0.0, "decide_s": 0.0, "decode_s": 0.0,
                        "scatter_s": 0.0, "decide_evals": 0,
-                       "transfer_bytes": 0}
+                       "transfer_bytes": 0, "resident_votes": 0}
         self._worker = threading.Thread(target=self._run,
                                         name="repro-serving-batcher",
                                         daemon=True)
@@ -272,7 +275,10 @@ class ServingService:
 
     def _flush(self, pending: list, stages: Stages) -> None:
         """One fused decide + vectorized decode per model present, then
-        scatter per-request slices back through the futures."""
+        scatter per-request slices back through the futures. The rows
+        of requests that read decision values go first, so that the
+        values fetched are a prefix of the batch; the order of rows
+        within a batch is invisible to callers."""
         with stages("serve.merge", "merge_s"):
             by_model: dict[str, list] = {}
             for r in pending:
@@ -282,15 +288,24 @@ class ServingService:
                 pred = self._predictor(name)
                 work = pred.work
                 with stages("serve.merge", "merge_s"):
+                    reqs = sorted(reqs,
+                                  key=lambda r: not pred.needs_values(r.op))
+                    n_values = sum(r.x.shape[0] for r in reqs
+                                   if pred.needs_values(r.op))
                     xcat = (reqs[0].x if len(reqs) == 1
                             else np.concatenate([r.x for r in reqs], axis=0))
                 with stages("serve.decide", "decide_s"):
-                    df = pred.decision_values(xcat)
+                    dec = pred.decide(xcat)
                 # decode ONCE per op over the merged batch (every op is
-                # columnwise), then slice per request
+                # columnwise), then slice per request; the values are
+                # fetched before the labels, so a slice that gave none
+                # counts as voted on the device alone
                 with stages("serve.decode", "decode_s"):
-                    decoded = {op: pred.decode(df, op)
-                               for op in {r.op for r in reqs}}
+                    df = (pred.decision_values(dec.head(n_values))
+                          if n_values else None)
+                    decoded = {op: pred.decode(
+                        df if pred.needs_values(op) else dec, op)
+                        for op in {r.op for r in reqs}}
                 for k, v in pred.work.items():
                     stages.add(k, v - work[k])
             except Exception as e:                 # noqa: BLE001

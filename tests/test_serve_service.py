@@ -90,6 +90,62 @@ def test_service_matches_predictor_outputs(ovo_problem):
                 assert (err <= tol).all(), (op, float(err.max()))
 
 
+def _one_window(svc, reqs):
+    """Submit ``reqs`` ((rows, op) pairs) inside one window of ``svc``
+    and return their answers."""
+    n0 = svc.stats["n_batches"]
+    futs = [svc.submit(xr, op=op) for xr, op in reqs]
+    got = [f.result(timeout=60) for f in futs]
+    assert svc.stats["n_batches"] == n0 + 1
+    return got
+
+
+def test_service_mixed_window_answers_each_request_its_own_rows(
+        ovo_problem):
+    """Label requests first, then values and decision_function requests,
+    in one window: the service decides the batch with the values rows
+    first, yet every future gets its own rows in its own order — the
+    labels of a lone ``predict``, and exactly the values a lone
+    ``decision_values`` gives those rows in the batch as served."""
+    x, _, model = ovo_problem
+    pred = serve.Predictor(serve.pack(model), engine="chunked").warmup(
+        (1, 2, 4, 8, 16, 32, 64))
+    sizes = [(3, "predict"), (1, "predict"), (5, "predict"),
+             (2, "values"), (4, "decision_function"), (1, "values"),
+             (2, "predict")]
+    starts = np.cumsum([0] + [n for n, _ in sizes])
+    reqs = [(x[a:a + n], op) for a, (n, op) in zip(starts, sizes)]
+    with serve.ServingService(pred, window_ms=500.0) as svc:
+        got = _one_window(svc, reqs)
+    served = [i for i, (_, op) in enumerate(reqs) if op != "predict"]
+    served += [i for i, (_, op) in enumerate(reqs) if op == "predict"]
+    df = pred.decision_values(np.concatenate([reqs[i][0] for i in served]))
+    at = dict(zip(served, np.cumsum([0] + [len(reqs[i][0])
+                                           for i in served])))
+    for i, ((xr, op), ans) in enumerate(zip(reqs, got)):
+        if op == "predict":
+            np.testing.assert_array_equal(ans, pred.predict(xr))
+        else:
+            want = df[:, at[i]:at[i] + len(xr)]
+            np.testing.assert_array_equal(ans, want)
+
+
+def test_service_labels_only_window_fetches_no_values(ovo_problem):
+    """A window of label requests alone moves its rows up and its labels
+    down, and nothing else: its slice is voted on the device."""
+    x, _, model = ovo_problem
+    packed = serve.pack(model)
+    pred = serve.Predictor(packed, engine="chunked").warmup((16,))
+    with serve.ServingService(pred, window_ms=500.0) as svc:
+        got = _one_window(svc, [(x[i:i + 3], "predict")
+                                for i in range(0, 12, 3)])
+    s = svc.stats                 # folded once the batch has ended
+    np.testing.assert_array_equal(np.concatenate(got), pred.predict(x[:12]))
+    bucket = 16                   # 12 rows pad to one 16-row slice
+    assert s["transfer_bytes"] == 4 * bucket * packed.n_features + 4 * bucket
+    assert s["resident_votes"] == 1
+
+
 def test_service_batches_a_burst(binary_problem):
     x, _, model = binary_problem
     svc = serve.ServingService(serve.pack(model), engine="chunked",
@@ -365,17 +421,18 @@ def test_quantize_helper_and_validation(binary_problem):
 # ---------------------------------------------------------- thread safety
 def test_predictor_concurrent_decision_values(ovo_problem):
     """Concurrent callers must not corrupt n_requests nor interleave
-    partially-written outputs: every thread's values match the serial
-    reference exactly, and the served-row and work counters are the
-    exact totals."""
+    partially-written outputs: every thread's values and labels match
+    the serial reference exactly, and the served-row and work counters
+    (``resident_votes`` of the labels included) are the exact totals."""
     x, _, model = ovo_problem
     pred = serve.Predictor(serve.pack(model), engine="chunked")
     pred.warmup(batch_sizes=(4, 16))
     slices = [(i % 40, 4 + (i % 3) * 12) for i in range(48)]
-    want, work = {}, {}
+    want, labels, work = {}, {}, {}
     for s, n in set(slices):
         before = pred.work
         want[(s, n)] = pred.decision_values(x[s:s + n])
+        labels[(s, n)] = pred.predict(x[s:s + n])
         work[(s, n)] = {k: v - before[k] for k, v in pred.work.items()}
     served0, work0 = pred.n_requests, pred.work
     barrier = threading.Barrier(8)
@@ -388,6 +445,8 @@ def test_predictor_concurrent_decision_values(ovo_problem):
                 s, n = slices[k]
                 np.testing.assert_array_equal(
                     pred.decision_values(x[s:s + n]), want[(s, n)])
+                np.testing.assert_array_equal(
+                    pred.predict(x[s:s + n]), labels[(s, n)])
         except Exception as e:                       # noqa: BLE001
             errors.append(e)
 
@@ -398,7 +457,8 @@ def test_predictor_concurrent_decision_values(ovo_problem):
     for t in threads:
         t.join(timeout=120)
     assert not errors, errors
-    assert pred.n_requests == served0 + sum(n for _, n in slices)
+    assert pred.n_requests == served0 + 2 * sum(n for _, n in slices)
+    assert pred.work["resident_votes"] > work0["resident_votes"]
     for k, v in pred.work.items():
         assert v == work0[k] + sum(work[sl][k] for sl in slices), k
 
@@ -652,6 +712,10 @@ def test_service_spans_nest_on_the_batcher_thread(ovo_problem, tmp_path):
                     for i in range(10)]
             for f in futs:
                 f.result(timeout=30)
+            # a batch's spans close after its futures resolve: join the
+            # batcher before the trace stops
+            svc.close(timeout=30)
+            assert not svc._worker.is_alive()
         finally:
             jax.profiler.stop_trace()
     finally:
@@ -683,17 +747,22 @@ def test_service_spans_nest_on_the_batcher_thread(ovo_problem, tmp_path):
     assert {"serve.collect", "serve.merge", "serve.decide", "serve.decode",
             "serve.scatter", "serve.upload", "serve.launch",
             "serve.fetch"} <= set(inner)
-    decides = [(s, e) for n, s, e, _ in events if n == "serve.decide"]
-    for name in ("serve.upload", "serve.launch", "serve.fetch"):
+    # the predictor uploads and launches inside serve.decide, and
+    # fetches (values, then labels) inside serve.decode
+    for stage, names in (("serve.decide", ("serve.upload", "serve.launch")),
+                         ("serve.decode", ("serve.fetch",))):
+        outer = [(s, e) for n, s, e, _ in events if n == stage]
         for n, s, e, _ in events:
-            if n == name:
-                assert any(ds <= s and e <= de for ds, de in decides)
+            if n in names:
+                assert any(ds <= s and e <= de for ds, de in outer), n
 
 
 def test_predictor_work_counts_padded_kernel_values_and_bytes(ovo_problem):
     """``Predictor.work`` counts what the calls launched: per slice and
     bank the padded rows times the bank's width times its tasks, and
-    every byte uploaded or fetched by decide and decode."""
+    every byte that crossed the link — rows up, then labels alone when
+    the device votes, or the pow2 prefix of decision values that holds
+    the rows asked for."""
     x, _, model = ovo_problem
     packed = serve.pack(model)
     pred = serve.Predictor(packed, engine="chunked", max_batch=8)
@@ -703,14 +772,25 @@ def test_predictor_work_counts_padded_kernel_values_and_bytes(ovo_problem):
     got = {k: v - before[k] for k, v in pred.work.items()}
     per_row = sum(g.sv_x.shape[0] * g.sv_x.shape[1] for g in packed.buckets)
     assert got["decide_evals"] == (8 + 4) * per_row
-    fetched = sum(4 * g.sv_x.shape[0] * (8 + 4) for g in packed.buckets)
-    decode = 4 * n_tasks * 16 + 4 * 16   # df up at width 16, labels back
-    assert got["transfer_bytes"] == 4 * (8 + 4) * d + fetched + decode
+    labels = 4 * (8 + 4)          # int32 class indices of both slices
+    assert got["transfer_bytes"] == 4 * (8 + 4) * d + labels
+    assert got["resident_votes"] == 2
     before = pred.work
     pred.decode(pred.decision_values(x[:3]), "values")
     got = {k: v - before[k] for k, v in pred.work.items()}
-    assert got["transfer_bytes"] == 4 * 4 * d + sum(
-        4 * g.sv_x.shape[0] * 4 for g in packed.buckets)
+    assert got["transfer_bytes"] == 4 * 4 * d + 4 * n_tasks * 4
+    assert got["resident_votes"] == 0
+    # three rows of values in an 11-row batch: the first slice's pow2
+    # prefix of 4 columns comes down, then both slices' labels; only the
+    # second slice was voted with none of its values fetched
+    before = pred.work
+    dec = pred.decide(x[:11])
+    pred.decision_values(dec.head(3))
+    pred.decode(dec, "predict")
+    got = {k: v - before[k] for k, v in pred.work.items()}
+    assert got["transfer_bytes"] == (4 * (8 + 4) * d + 4 * n_tasks * 4
+                                     + labels)
+    assert got["resident_votes"] == 1
 
 
 def test_service_stats_fold_the_predictors_work(ovo_problem):
@@ -725,3 +805,6 @@ def test_service_stats_fold_the_predictors_work(ovo_problem):
     s = svc.stats
     for k, v in pred.work.items():
         assert s[k] == v - before[k] > 0, k
+    # labels alone, one slice a batch: each batch's slice was voted on
+    # the device and none of its decision values came down
+    assert s["resident_votes"] == s["n_batches"]

@@ -10,6 +10,7 @@ different XLA programs (padded batch buckets, vmapped banks), so they
 are not compared with each other bit for bit: their f32 accumulation
 order is the compiler's to choose.
 """
+import dataclasses
 import io
 
 import numpy as np
@@ -115,6 +116,65 @@ def test_micro_batch_slicing_matches_single_shot(prob, request):
     np.testing.assert_array_equal(sliced.predict(xt), whole.predict(xt))
     np.testing.assert_array_almost_equal_nulp(
         sliced.decision_values(xt), whole.decision_values(xt), nulp=4)
+
+
+# -------------------------------------------------- device-resident vote
+def _with_empty_bank(packed):
+    """The pack with its first bank's first task moved to a width-0 bank
+    of its own: that task serves its bias, and the banks no longer hold
+    the tasks in order."""
+    g = packed.buckets[0]
+    empty = serve.TaskBucket(task_ids=g.task_ids[:1],
+                             sv_x=np.zeros((1, 0, packed.n_features),
+                                           g.sv_x.dtype),
+                             sv_coef=np.zeros((1, 0), g.sv_coef.dtype),
+                             b=g.b[:1], sv_counts=np.zeros(1, np.int64))
+    rest = g._replace(task_ids=g.task_ids[1:], sv_x=g.sv_x[1:],
+                      sv_coef=g.sv_coef[1:], b=g.b[1:],
+                      sv_counts=g.sv_counts[1:])
+    return dataclasses.replace(
+        packed, buckets=(rest,) + packed.buckets[1:] + (empty,))
+
+
+def _resident_pack(prob, variant, request):
+    x, y, model = request.getfixturevalue(prob)
+    if variant == "lowrank":
+        model = SVC(engine="rff", rank=32, gamma=0.5,
+                    strategy=model.strategy).fit(x, y)
+    packed = serve.pack(model, **({"sv_dtype": "bf16"}
+                                  if variant == "bf16" else {}))
+    if variant == "empty_bank":
+        packed = _with_empty_bank(packed)
+    return x, packed
+
+
+@pytest.mark.parametrize("engine", ["chunked", "pallas"])
+@pytest.mark.parametrize("variant", ["fp32", "empty_bank", "bf16",
+                                     "lowrank"])
+@pytest.mark.parametrize("prob", ["ovo_problem", "ovr_problem"])
+def test_device_vote_matches_host_decode(prob, variant, engine, request):
+    """A batch decided on the device and voted there gives exactly the
+    labels of the host decode of its fetched decision values, and the
+    values fetched as a pow2 prefix are those values bit for bit — over
+    several slices with a ragged last one (max_batch 8, 21 rows)."""
+    x, packed = _resident_pack(prob, variant, request)
+    if (prob, variant) == ("ovo_problem", "fp32"):
+        assert len(packed.buckets) > 1          # several banks
+    pred = serve.Predictor(packed, engine=engine, max_batch=8)
+    xt = np.asarray(x[:21], np.float32)
+    df = pred.decision_values(xt)
+    want = pred.decode(df, "predict")
+    before = pred.work["resident_votes"]
+    np.testing.assert_array_equal(pred.predict(xt), want)
+    assert pred.work["resident_votes"] == before + 3
+    dec = pred.decide(xt)
+    for k in (0, 1, 3, 8, 13, 21):
+        head = pred.decision_values(dec.head(k))
+        np.testing.assert_array_equal(head, df[:, :k])
+        np.testing.assert_array_equal(
+            pred.decode(dec.head(k), "decision_function"), df[:, :k])
+    np.testing.assert_array_equal(pred.decode(dec, "predict"), want)
+    np.testing.assert_array_equal(pred.decode(dec, "values"), df)
 
 
 # ---------------------------------------------------------------- decode
@@ -355,33 +415,49 @@ def test_predictor_program_cache_is_batch_bucketed(ovo_problem):
     assert pred.n_programs == n0 + len(model._serving_buckets)
 
 
-def test_predictor_replay_within_compile_budget(ovo_problem,
+def _replay(pred, xt, ops):
+    """One request of ``xt`` rows: a plain predict, or the service's mix
+    of ops — decided once, a prefix of values fetched, labels voted on
+    the device — beside a decision_function call."""
+    if ops == "predict":
+        return pred.predict(xt)
+    dec = pred.decide(xt)
+    pred.decision_values(dec.head((len(xt) + 1) // 2))
+    pred.decode(dec, "predict")
+    pred.decision_function(xt)
+
+
+@pytest.mark.parametrize("ops", ["predict", "mixed"])
+def test_predictor_replay_within_compile_budget(ovo_problem, ops,
                                                 compile_guard):
     """Runtime backstop for the pow2 padding ladder (analysis R001):
     after warmup at a bucket, every request size inside that bucket
-    replays through the warm programs — zero fresh XLA compiles. The
-    guard fails this test the day a change starts keying programs on
-    raw request shapes again."""
+    replays through the warm programs — zero fresh XLA compiles, for
+    labels alone and for a mix of ops. The guard fails this test the
+    day a change starts keying programs on raw request shapes again."""
     x, _, model = ovo_problem
     pred = serve.Predictor(serve.pack(model), engine="chunked")
     pred.warmup(batch_sizes=(32,))
     assert pred.n_decode_programs == 1
     with compile_guard(budget=0, note="warm-bucket replay") as g:
         for nt in range(17, 33):
-            pred.predict(x[:nt])
+            _replay(pred, x[:nt], ops)
     assert g.count == 0 and pred.n_programs == len(model._serving_buckets)
     assert pred.n_decode_programs == 1
 
 
+@pytest.mark.parametrize("ops", ["predict", "mixed"])
 @pytest.mark.parametrize("prob, n_widths", [("ovo_problem", 3),
                                             ("ovr_problem", 3),
                                             ("binary_problem", 0),
                                             ("svr_problem", 0)])
-def test_decode_programs_one_per_warmed_width(prob, n_widths, request,
+def test_decode_programs_one_per_warmed_width(prob, n_widths, ops, request,
                                               compile_guard):
-    """Warm-up compiles one decode program per multiclass ladder width
-    (binary and SVR decode on the host: none), counted apart from the
-    decide programs; replays inside the warm widths compile nothing."""
+    """Warm-up compiles one vote program per multiclass ladder width —
+    the assembly program that votes each slice on the device (binary
+    and SVR decode on the host: none) — counted apart from the decide
+    programs; replays inside the warm widths compile nothing, a pow2
+    prefix of values included."""
     x, _, model = request.getfixturevalue(prob)
     pred = serve.Predictor(serve.pack(model), engine="chunked")
     pred.warmup(batch_sizes=(1, 5, 32))
@@ -389,7 +465,7 @@ def test_decode_programs_one_per_warmed_width(prob, n_widths, request,
     n_programs = pred.n_programs
     with compile_guard(budget=0, note="warm decode replay") as g:
         for nt in (1, 5, 6, 7, 8, 17, 20, 32):
-            pred.predict(x[:nt])
+            _replay(pred, x[:nt], ops)
     assert g.count == 0
     assert pred.n_decode_programs == n_widths
     assert pred.n_programs == n_programs

@@ -40,11 +40,22 @@ chunked ``engine.matvec``) gates the reported convergence.
 Everything is mask-aware so that one ``vmap``/``shard_map`` program can
 drive many padded one-vs-one tasks (the MPI layer in ``core.dist``).
 
-``sharded_binary_smo`` is the complementary axis of parallelism: ONE
-binary problem data-parallel across the mesh (samples sharded, selection
-made globally exact by ``combine_selection`` — the paper's per-rank
-block-reduce + MPI_Allreduce), for the single large QP that task
-parallelism cannot help with.
+One driver, two layouts
+-----------------------
+``sharded_solve_qp`` is the complementary axis of parallelism: ONE QP
+data-parallel across a mesh axis, samples in contiguous blocks, for the
+single large problem that task parallelism cannot help with. It runs the
+same driver (``_smo_solve``) and the same iteration (``_smo_iteration``)
+as ``solve_qp``, inside a ``shard_map`` body. What differs is only where
+the per-sample vectors live, and that sits behind a ``_Layout`` seam:
+``_LOCAL`` indexes plainly, ``_sharded_layout`` turns each access into
+the paper's MPI stage (per-rank block-reduce + all_gather + the
+first-occurrence ``combine_selection`` for selection, one stacked psum of
+owner-masked picks for the pair's scalars). Shrinking, warm starts, the
+stopping rule and the float association of the f-update are therefore
+written once. The sharded seam reads ``_sharded_selection`` as a module
+global when it is traced; ``bench/control.py`` relies on that to swap in
+a selection without the exchange.
 
 The generalized QP core
 -----------------------
@@ -160,10 +171,11 @@ def _pair_update(a_i, a_j, y_i, y_j, f_i, f_j, k_ii, k_jj, k_ij,
     the segment the equality constraint cuts out of the box
     [lo_i, hi_i] x [lo_j, hi_j], with exact-bound snapping: f32 residues
     near the bounds would otherwise keep dead multipliers inside
-    I_up/I_low and stall working-set selection. Shared verbatim by the
-    single-device and sharded iterations — this is what keeps their
-    numerics identical. (At the classification box [0, C] every
-    expression below reduces bit-for-bit to the pre-QP-spec form.)
+    I_up/I_low and stall working-set selection. The one iteration calls
+    it in both layouts, on replicated scalars — this is what keeps the
+    local and sharded numerics identical. (At the classification box
+    [0, C] every expression below reduces bit-for-bit to the pre-QP-spec
+    form.)
     """
     eta = jnp.maximum(k_ii + k_jj - 2.0 * k_ij, 1e-12)
     a_j_new = a_j + y_j * (f_i - f_j) / eta
@@ -257,7 +269,30 @@ def kkt_violation(alpha, y, f, lo, hi, tol: float = 0.0, mask=None,
     return jnp.maximum(0.0, jnp.maximum(r - b_up, b_low - r))
 
 
-def _smo_iteration(state: _State, *, y, mask, lo, hi,
+class _Layout(NamedTuple):
+    """The seam between the one SMO driver and where its per-sample
+    vectors live: whole on one device (``_LOCAL``) or in contiguous blocks
+    over a mesh axis (``_sharded_layout``). Indices are global; what
+    ``select`` / ``argmax`` / ``replicate`` / ``count`` return is the same
+    on every shard."""
+
+    select: Callable     # (f, alpha, y, mask, lo, hi) -> _selection's 4
+    argmax: Callable     # v -> global index of v's first maximum
+    pick: Callable       # (vec, g) -> this device's share of vec[g]
+    replicate: Callable  # (*picks) -> the picked scalars
+    add: Callable        # (vec, g, d) -> vec with d added at g
+    count: Callable      # bool vec -> global count of True
+
+
+# plain indexing, nothing exchanged
+_LOCAL = _Layout(select=_selection, argmax=jnp.argmax,
+                 pick=lambda vec, g: vec[g],
+                 replicate=lambda *picks: picks,
+                 add=lambda vec, g, d: vec.at[g].add(d),
+                 count=lambda m: jnp.sum(m.astype(jnp.int32)))
+
+
+def _smo_iteration(state: _State, *, lay, y, mask, lo, hi,
                    engine: KE.KernelEngine, cfg: SMOConfig, diag=None,
                    shrink: bool = False):
     """One working-set pair update + f-cache refresh over the active set.
@@ -267,15 +302,17 @@ def _smo_iteration(state: _State, *, y, mask, lo, hi,
     j maximizes the guaranteed objective gain (f_j - f_i)^2 / (2 eta_ij)
     over I_low — pays one already-needed kernel row, typically converges
     in ~2x fewer iterations.
+
+    ``lay`` is the ``_Layout`` the per-sample vectors live in.
     """
     alpha, f = state.alpha, state.f
     sel_mask = (mask & state.active) if shrink else mask
-    b_up, i_up, b_low, i_low = _selection(f, alpha, y, sel_mask, lo, hi)
+    b_up, i_up, b_low, i_low = lay.select(f, alpha, y, sel_mask, lo, hi)
     step_live = b_low > b_up + 2.0 * cfg.tol  # not yet converged
 
     j = i_up
     row_j, cache = engine.row(j, state.cache)
-    k_jj = row_j[j]
+    (k_jj,) = lay.replicate(lay.pick(row_j, j))
 
     if cfg.selection == "second":
         # gain_l = (f_l - b_up)^2 / (2 eta_lj) over valid I_low partners
@@ -286,25 +323,30 @@ def _smo_iteration(state: _State, *, y, mask, lo, hi,
         eta_all = jnp.maximum(diag + k_jj - 2.0 * row_j, 1e-12)
         df = f - b_up
         gain = jnp.where(low_mask & (df > 0.0), df * df / eta_all, -jnp.inf)
-        i = jnp.argmax(gain)
+        i = lay.argmax(gain)
     else:
         i = i_low
 
-    y_i, y_j = y[i], y[j]
-    a_i, a_j = alpha[i], alpha[j]
-
+    # the pair's scalars: y and alpha are picked before row i is fetched
+    # (the order the local program is traced in), and the sharded layout
+    # replicates all twelve in one collective
+    y_i, y_j = lay.pick(y, i), lay.pick(y, j)
+    a_i, a_j = lay.pick(alpha, i), lay.pick(alpha, j)
     row_i, cache = engine.row(i, cache)
-    k_ii = row_i[i]
-    k_ij = row_i[j]
-    a_i_new, a_j_new = _pair_update(a_i, a_j, y_i, y_j, f[i], f[j],
+    (y_i, y_j, a_i, a_j, k_ii, k_ij, f_i, f_j,
+     lo_i, hi_i, lo_j, hi_j) = lay.replicate(
+        y_i, y_j, a_i, a_j, lay.pick(row_i, i), lay.pick(row_i, j),
+        lay.pick(f, i), lay.pick(f, j), lay.pick(lo, i), lay.pick(hi, i),
+        lay.pick(lo, j), lay.pick(hi, j))
+    a_i_new, a_j_new = _pair_update(a_i, a_j, y_i, y_j, f_i, f_j,
                                     k_ii, k_jj, k_ij,
-                                    lo[i], hi[i], lo[j], hi[j])
+                                    lo_i, hi_i, lo_j, hi_j)
 
     d_i = jnp.where(step_live, a_i_new - a_i, 0.0)
     d_j = jnp.where(step_live, a_j_new - a_j, 0.0)
 
-    alpha = alpha.at[i].add(d_i)
-    alpha = alpha.at[j].add(d_j)
+    alpha = lay.add(alpha, i, d_i)
+    alpha = lay.add(alpha, j, d_j)
     # the "one thread per sample" stage: every active sample updates its
     # f entry (shrinking restricts the update to the active set; frozen
     # entries are reconstructed exactly at the un-shrink check). NOTE:
@@ -322,6 +364,93 @@ def _smo_iteration(state: _State, *, y, mask, lo, hi,
                           b_up=b_up,
                           b_low=b_low,
                           cache=cache)
+
+
+def _smo_solve(eng, lay, y, p, lo, hi, mask, cfg: SMOConfig,
+               alpha0=None) -> SMOResult:
+    """The SMO driver, shared by ``solve_qp`` and the ``shard_map`` body
+    of ``sharded_solve_qp``: state init, the while/fori convergence loop
+    with its shrinking state machine, the final selection and the bias.
+    ``y`` / ``p`` / ``lo`` / ``hi`` / ``mask`` are float32 (bool) vectors
+    in ``lay``'s layout and ``eng`` is an engine over the same rows."""
+    shrink = cfg.shrink_every > 0
+
+    if alpha0 is None:
+        a0 = jnp.zeros(y.shape, jnp.float32)
+        f0 = y * p  # alpha = 0  =>  f_i = y_i p_i (classification: -y_i)
+    else:
+        a0 = jnp.clip(jnp.asarray(alpha0, jnp.float32), lo, hi) * mask
+        f0 = eng.matvec(a0 * y) + y * p
+    state0 = _State(alpha=a0, f=f0,
+                    n_iter=jnp.zeros((), jnp.int32),
+                    b_up=jnp.asarray(-1.0, jnp.float32),
+                    b_low=jnp.asarray(1.0, jnp.float32),
+                    active=mask,
+                    done=jnp.asarray(False),
+                    checks=jnp.zeros((), jnp.int32),
+                    cache=eng.init_cache())
+
+    diag = eng.diag() if cfg.selection == "second" else None
+    iteration = partial(_smo_iteration, lay=lay, y=y, mask=mask, lo=lo,
+                        hi=hi, engine=eng, cfg=cfg, diag=diag, shrink=shrink)
+
+    def cond(state: _State):
+        return (~state.done) & (state.n_iter < cfg.max_iter)
+
+    def body(state: _State):
+        # paper Fig. 3: run `check_every` device iterations between checks
+        state = jax.lax.fori_loop(0, cfg.check_every,
+                                  lambda _, s: iteration(s), state)
+        # b_up/b_low are replicated, so every shard takes the same branch
+        conv_active = state.b_low <= state.b_up + 2.0 * cfg.tol
+        if not shrink:
+            return state._replace(done=conv_active)
+        state = state._replace(checks=state.checks + 1)
+
+        def unshrink(s: _State):
+            # exact gradient for ALL samples via one chunked matvec, then
+            # the un-shrunk KKT re-check; resume on the full set if the
+            # shrunk optimum does not survive it
+            f_full = eng.matvec(s.alpha * y) + y * p
+            b_up, _, b_low, _ = lay.select(f_full, s.alpha, y, mask, lo, hi)
+            return s._replace(f=f_full, active=mask,
+                              done=b_low <= b_up + 2.0 * cfg.tol,
+                              b_up=b_up, b_low=b_low)
+
+        def maybe_shrink(s: _State):
+            do = (s.checks % cfg.shrink_every) == 0
+            shrunk = _shrink_active(s.f, s.alpha, y, mask, s.b_up,
+                                    s.b_low, lo, hi, cfg) & s.active
+            return s._replace(active=jnp.where(do, shrunk, s.active))
+
+        return jax.lax.cond(conv_active, unshrink, maybe_shrink, state)
+
+    state = jax.lax.while_loop(cond, body, state0)
+    # final selection for the reported gap / bias — on the UN-shrunk set
+    # (shrinking may leave frozen entries with a stale f if the iteration
+    # cap fired mid-phase; reconstruct before reporting)
+    f_final = eng.matvec(state.alpha * y) + y * p if shrink else state.f
+    b_up, _, b_low, _ = lay.select(f_final, state.alpha, y, mask, lo, hi)
+    b = -(b_up + b_low) / 2.0
+    n_active = lay.count(state.active & mask)
+    return SMOResult(alpha=state.alpha * mask, b=b, n_iter=state.n_iter,
+                     converged=b_low <= b_up + 2.0 * cfg.tol,
+                     gap=b_low - b_up, n_active=n_active)
+
+
+def _check_box(lo, hi, who: str):
+    """The solver starts at alpha = 0 (f0 = y*p is only the gradient
+    there), so 0 must be inside the box; a box excluding 0 would silently
+    return an infeasible "optimum". Validated whenever the bounds are
+    concrete (they are for every shipped spec, even under jit — constants
+    created inside a trace stay concrete)."""
+    if isinstance(lo, jax.core.Tracer) or isinstance(hi, jax.core.Tracer):
+        return
+    if bool(jnp.any((lo > 0.0) | (hi < 0.0))):
+        raise ValueError(
+            f"{who} initializes alpha = 0, which must be feasible: need "
+            "lo <= 0 <= hi elementwise (shift the variables to move the "
+            "box)")
 
 
 def _resolve_engine(x, kernel: K.KernelParams, cfg: SMOConfig,
@@ -397,86 +526,13 @@ def solve_qp(x: jax.Array,
     p = jnp.asarray(p, jnp.float32)
     lo = jnp.broadcast_to(jnp.asarray(lo, jnp.float32), (n,))
     hi = jnp.broadcast_to(jnp.asarray(hi, jnp.float32), (n,))
-    # the solver starts at alpha = 0 (f0 = y*p is only the gradient
-    # there), so 0 must be inside the box; a box excluding 0 would
-    # silently return an infeasible "optimum". Validate whenever the
-    # bounds are concrete (they are for every shipped spec, even under
-    # jit — constants created inside a trace stay concrete).
-    if not (isinstance(lo, jax.core.Tracer)
-            or isinstance(hi, jax.core.Tracer)):
-        if bool(jnp.any((lo > 0.0) | (hi < 0.0))):
-            raise ValueError(
-                "solve_qp initializes alpha = 0, which must be feasible: "
-                "need lo <= 0 <= hi elementwise (shift the variables to "
-                "move the box)")
+    _check_box(lo, hi, "solve_qp")
     if mask is None:
         mask = jnp.ones((n,), dtype=bool)
     mask = mask & (jnp.abs(y) > 0.5)  # padded signs may be 0
 
     eng = _resolve_engine(x, kernel, cfg, engine, gram, row_fn)
-    shrink = cfg.shrink_every > 0
-
-    if alpha0 is None:
-        a0 = jnp.zeros((n,), jnp.float32)
-        f0 = y * p  # alpha = 0  =>  f_i = y_i p_i (classification: -y_i)
-    else:
-        a0 = jnp.clip(jnp.asarray(alpha0, jnp.float32), lo, hi) * mask
-        f0 = eng.matvec(a0 * y) + y * p
-    state0 = _State(alpha=a0, f=f0,
-                    n_iter=jnp.zeros((), jnp.int32),
-                    b_up=jnp.asarray(-1.0, jnp.float32),
-                    b_low=jnp.asarray(1.0, jnp.float32),
-                    active=mask,
-                    done=jnp.asarray(False),
-                    checks=jnp.zeros((), jnp.int32),
-                    cache=eng.init_cache())
-
-    diag = eng.diag() if cfg.selection == "second" else None
-    iteration = partial(_smo_iteration, y=y, mask=mask, lo=lo, hi=hi,
-                        engine=eng, cfg=cfg, diag=diag, shrink=shrink)
-
-    def cond(state: _State):
-        return (~state.done) & (state.n_iter < cfg.max_iter)
-
-    def body(state: _State):
-        # paper Fig. 3: run `check_every` device iterations between checks
-        state = jax.lax.fori_loop(0, cfg.check_every,
-                                  lambda _, s: iteration(s), state)
-        conv_active = state.b_low <= state.b_up + 2.0 * cfg.tol
-        if not shrink:
-            return state._replace(done=conv_active)
-        state = state._replace(checks=state.checks + 1)
-
-        def unshrink(s: _State):
-            # exact gradient for ALL samples via one chunked matvec, then
-            # the un-shrunk KKT re-check; resume on the full set if the
-            # shrunk optimum does not survive it
-            f_full = eng.matvec(s.alpha * y) + y * p
-            b_up, _, b_low, _ = _selection(f_full, s.alpha, y, mask,
-                                           lo, hi)
-            return s._replace(f=f_full, active=mask,
-                              done=b_low <= b_up + 2.0 * cfg.tol,
-                              b_up=b_up, b_low=b_low)
-
-        def maybe_shrink(s: _State):
-            do = (s.checks % cfg.shrink_every) == 0
-            shrunk = _shrink_active(s.f, s.alpha, y, mask, s.b_up,
-                                    s.b_low, lo, hi, cfg) & s.active
-            return s._replace(active=jnp.where(do, shrunk, s.active))
-
-        return jax.lax.cond(conv_active, unshrink, maybe_shrink, state)
-
-    state = jax.lax.while_loop(cond, body, state0)
-    # final selection for the reported gap / bias — on the UN-shrunk set
-    # (shrinking may leave frozen entries with a stale f if the iteration
-    # cap fired mid-phase; reconstruct before reporting)
-    f_final = eng.matvec(state.alpha * y) + y * p if shrink else state.f
-    b_up, _, b_low, _ = _selection(f_final, state.alpha, y, mask, lo, hi)
-    b = -(b_up + b_low) / 2.0
-    n_active = jnp.sum((state.active & mask).astype(jnp.int32))
-    return SMOResult(alpha=state.alpha * mask, b=b, n_iter=state.n_iter,
-                     converged=b_low <= b_up + 2.0 * cfg.tol,
-                     gap=b_low - b_up, n_active=n_active)
+    return _smo_solve(eng, _LOCAL, y, p, lo, hi, mask, cfg, alpha0)
 
 
 def _classification_spec(y, c):
@@ -500,6 +556,12 @@ def _svr_spec(y, epsilon, c):
     lo = jnp.zeros((2 * n,), jnp.float32)
     hi = jnp.full((2 * n,), c, jnp.float32)
     return s, p, lo, hi
+
+
+def _doubled(x, mask):
+    """The doubled epsilon-SVR sample layout: [x; x] and [mask; mask]."""
+    return (jnp.concatenate([x, x], axis=0),
+            None if mask is None else jnp.concatenate([mask, mask]))
 
 
 def binary_smo(x: jax.Array,
@@ -586,36 +648,20 @@ def svr_smo(x: jax.Array,
     n = x.shape[0]
     x = jnp.asarray(x, jnp.float32)
     s, p, lo, hi = _svr_spec(y, epsilon, cfg.C)
-    x2 = jnp.concatenate([x, x], axis=0)
-    m2 = None
-    if mask is not None:
-        m2 = jnp.concatenate([mask, mask])
+    x2, m2 = _doubled(x, mask)
     r = solve_qp(x2, s, p, lo, hi, m2, cfg=cfg, kernel=kernel,
                  engine=engine, alpha0=alpha0)
     return _svr_result(r, n)
 
 
 # --------------------------------------------------------------------------
-# Sharded single-problem SMO: data-parallel over the SAMPLE axis.
-#
-# The paper's MPI-CUDA solver is data-parallel WITHIN one QP: every rank
-# owns a row block of the Gram matrix, working-set selection is a per-rank
-# block-reduce followed by an MPI_Allreduce, and the f-cache update is
-# embarrassingly parallel over the rank's samples. The JAX analog below
-# shards x / y / alpha / f over a mesh axis via shard_map:
-#
-#   per-rank block-reduce   ->  masked min/argmin on the LOCAL shard
-#   MPI_Allreduce           ->  all_gather of P (value, global index)
-#                               pairs + an identical local reduction
-#                               (combine_selection) on every shard
-#   Gram row block          ->  ShardedKernelEngine.row — x is replicated
-#                               (all-gathered once), rows are local compute
-#   scalar pair state       ->  one psum of owner-masked picks per step
-#
-# The combine preserves FIRST-OCCURRENCE argmin/argmax semantics (shards
-# are contiguous sample blocks in axis order), so the selected violating
-# pair — and therefore the whole optimization trajectory — is bit-for-bit
-# the single-device one.
+# Sharded single-problem SMO: the collectives behind ``_sharded_layout``.
+# A shard's block-reduce becomes global through an all_gather of P
+# (value, global index) pairs and the same reduction on every shard;
+# ``combine_selection`` keeps FIRST-OCCURRENCE argmin/argmax semantics
+# (shards are contiguous blocks in axis order), so the selected pair is
+# the single-device one. x is all-gathered once (ShardedKernelEngine);
+# Gram rows are local compute.
 # --------------------------------------------------------------------------
 def _combine_min(vals, idxs):
     s = jnp.argmin(vals)
@@ -669,156 +715,35 @@ def _owner_pick(vec, g, me):
     return jnp.where((g // n_local) == me, vec[g % n_local], 0.0)
 
 
-def _sharded_smo_iteration(state: _State, *, y, mask, lo, hi,
-                           engine: KE.ShardedKernelEngine, cfg: SMOConfig,
-                           diag=None, shrink: bool = False):
-    """One pair update with all per-sample state sharded over engine.axis.
-
-    Mirrors ``_smo_iteration`` stage for stage; every divergence is a
-    collective: selection all-gathers per-shard extrema, the pair's
-    scalars (f, alpha, y, box bounds, kernel entries at i and j) arrive
-    via ONE stacked psum of owner-masked picks, and the f-cache update
-    applies the shared ``_pair_update`` deltas to the local slice of the
-    two kernel rows.
-    """
-    axis = engine.axis
-    alpha, f = state.alpha, state.f
+def _sharded_layout(axis: str) -> _Layout:
+    """The seam inside a ``shard_map`` body whose sample axis is
+    ``axis``: selection is the all-gathered block-reduce, a pick is the
+    owner's entry (0 elsewhere), and ``replicate`` sums the picks in one
+    stacked psum. ``select`` looks ``_sharded_selection`` up as a module
+    global when it is traced, never binds it here: ``bench/control.py``'s
+    ``no_exchange`` fault swaps that global (then clears
+    ``_sharded_smo_program``'s cache) to show that the benchmark refuses
+    a fit without the exchange."""
     me = jax.lax.axis_index(axis)
-    n_local = y.shape[0]
-    sel_mask = (mask & state.active) if shrink else mask
-    b_up, i_up, b_low, i_low = _sharded_selection(f, alpha, y, sel_mask,
-                                                  lo, hi, axis)
-    step_live = b_low > b_up + 2.0 * cfg.tol
 
-    j = i_up  # global index
-    row_j, cache = engine.row(j, state.cache)
-    k_jj = jax.lax.psum(_owner_pick(row_j, j, me), axis)
+    def argmax(v):  # local block-reduce + the first-occurrence combine
+        li = jnp.argmax(v)
+        return _combine_max(jax.lax.all_gather(v[li], axis),
+                            jax.lax.all_gather(me * v.shape[0] + li,
+                                               axis))[1]
 
-    if cfg.selection == "second":
-        # local gain block-reduce + the same first-occurrence combine
-        eps = 1e-6 * (hi - lo)
-        pos, neg = y > 0, y <= 0
-        low_mask = sel_mask & ((pos & (alpha > lo + eps))
-                               | (neg & (alpha < hi - eps)))
-        eta_all = jnp.maximum(diag + k_jj - 2.0 * row_j, 1e-12)
-        df = f - b_up
-        gain = jnp.where(low_mask & (df > 0.0), df * df / eta_all, -jnp.inf)
-        li = jnp.argmax(gain)
-        _, i = _combine_max(jax.lax.all_gather(gain[li], axis),
-                            jax.lax.all_gather(me * n_local + li, axis))
-    else:
-        i = i_low
+    def add(vec, g, d):
+        n_local = vec.shape[0]
+        return vec.at[g % n_local].add(
+            jnp.where((g // n_local) == me, d, 0.0))
 
-    row_i, cache = engine.row(i, cache)
-    # every scalar the update needs, in one collective
-    picks = jnp.stack([
-        _owner_pick(f, i, me), _owner_pick(f, j, me),
-        _owner_pick(alpha, i, me), _owner_pick(alpha, j, me),
-        _owner_pick(y, i, me), _owner_pick(y, j, me),
-        _owner_pick(row_i, i, me), _owner_pick(row_i, j, me),
-        _owner_pick(lo, i, me), _owner_pick(hi, i, me),
-        _owner_pick(lo, j, me), _owner_pick(hi, j, me),
-    ])
-    (f_i, f_j, a_i, a_j, y_i, y_j, k_ii, k_ij,
-     lo_i, hi_i, lo_j, hi_j) = jax.lax.psum(picks, axis)
-    a_i_new, a_j_new = _pair_update(a_i, a_j, y_i, y_j, f_i, f_j,
-                                    k_ii, k_jj, k_ij,
-                                    lo_i, hi_i, lo_j, hi_j)
-
-    d_i = jnp.where(step_live, a_i_new - a_i, 0.0)
-    d_j = jnp.where(step_live, a_j_new - a_j, 0.0)
-
-    alpha = alpha.at[i % n_local].add(
-        jnp.where((i // n_local) == me, d_i, 0.0))
-    alpha = alpha.at[j % n_local].add(
-        jnp.where((j // n_local) == me, d_j, 0.0))
-    # the "one thread per sample" stage, on this shard's samples only;
-    # float association matches _smo_iteration branch for branch
-    if shrink:
-        upd = d_i * y_i * row_i + d_j * y_j * row_j
-        f = jnp.where(state.active, f + upd, f)
-    else:
-        f = f + d_i * y_i * row_i + d_j * y_j * row_j
-
-    return state._replace(alpha=alpha,
-                          f=f,
-                          n_iter=state.n_iter + step_live.astype(jnp.int32),
-                          b_up=b_up,
-                          b_low=b_low,
-                          cache=cache)
-
-
-def _sharded_smo_solve(x, y, p, lo, hi, mask, *, cfg: SMOConfig,
-                       kernel: K.KernelParams, ecfg: KE.EngineConfig):
-    """shard_map body: ``solve_qp`` with (n_local,) shards of
-    x/y/p/lo/hi/mask.
-
-    Scalars (b, n_iter, converged, gap, n_active) come out replicated;
-    alpha comes out sharded. Structured like ``solve_qp`` — same
-    while/fori convergence loop, same shrinking state machine — with the
-    sharded iteration/selection and a psum'd n_active.
-    """
-    axis = ecfg.shard_axis
-    y = y.astype(jnp.float32)
-    mask = mask & (jnp.abs(y) > 0.5)  # padded signs are 0
-
-    eng = KE.ShardedKernelEngine(x.astype(jnp.float32), kernel, ecfg)
-    shrink = cfg.shrink_every > 0
-    n_local = y.shape[0]
-
-    f0 = y * p
-    state0 = _State(alpha=jnp.zeros((n_local,), jnp.float32), f=f0,
-                    n_iter=jnp.zeros((), jnp.int32),
-                    b_up=jnp.asarray(-1.0, jnp.float32),
-                    b_low=jnp.asarray(1.0, jnp.float32),
-                    active=mask,
-                    done=jnp.asarray(False),
-                    checks=jnp.zeros((), jnp.int32),
-                    cache=eng.init_cache())
-
-    diag = eng.diag() if cfg.selection == "second" else None
-    iteration = partial(_sharded_smo_iteration, y=y, mask=mask, lo=lo,
-                        hi=hi, engine=eng, cfg=cfg, diag=diag,
-                        shrink=shrink)
-
-    def cond(state: _State):
-        return (~state.done) & (state.n_iter < cfg.max_iter)
-
-    def body(state: _State):
-        state = jax.lax.fori_loop(0, cfg.check_every,
-                                  lambda _, s: iteration(s), state)
-        # b_up/b_low are replicated, so every shard takes the same branch
-        conv_active = state.b_low <= state.b_up + 2.0 * cfg.tol
-        if not shrink:
-            return state._replace(done=conv_active)
-        state = state._replace(checks=state.checks + 1)
-
-        def unshrink(s: _State):
-            f_full = eng.matvec(s.alpha * y) + y * p
-            b_up, _, b_low, _ = _sharded_selection(f_full, s.alpha, y,
-                                                   mask, lo, hi, axis)
-            return s._replace(f=f_full, active=mask,
-                              done=b_low <= b_up + 2.0 * cfg.tol,
-                              b_up=b_up, b_low=b_low)
-
-        def maybe_shrink(s: _State):
-            do = (s.checks % cfg.shrink_every) == 0
-            shrunk = _shrink_active(s.f, s.alpha, y, mask, s.b_up,
-                                    s.b_low, lo, hi, cfg) & s.active
-            return s._replace(active=jnp.where(do, shrunk, s.active))
-
-        return jax.lax.cond(conv_active, unshrink, maybe_shrink, state)
-
-    state = jax.lax.while_loop(cond, body, state0)
-    f_final = eng.matvec(state.alpha * y) + y * p if shrink else state.f
-    b_up, _, b_low, _ = _sharded_selection(f_final, state.alpha, y, mask,
-                                           lo, hi, axis)
-    b = -(b_up + b_low) / 2.0
-    n_active = jax.lax.psum(
-        jnp.sum((state.active & mask).astype(jnp.int32)), axis)
-    return SMOResult(alpha=state.alpha * mask, b=b, n_iter=state.n_iter,
-                     converged=b_low <= b_up + 2.0 * cfg.tol,
-                     gap=b_low - b_up, n_active=n_active)
+    return _Layout(
+        select=lambda *a: _sharded_selection(*a, axis),
+        argmax=argmax, pick=lambda vec, g: _owner_pick(vec, g, me),
+        replicate=lambda *picks: tuple(jax.lax.psum(jnp.stack(picks),
+                                                    axis)),
+        add=add,
+        count=lambda m: jax.lax.psum(jnp.sum(m.astype(jnp.int32)), axis))
 
 
 @lru_cache(maxsize=64)
@@ -826,11 +751,18 @@ def _sharded_smo_program(mesh: Mesh, axis: str, cfg: SMOConfig,
                          kernel: K.KernelParams, ecfg: KE.EngineConfig):
     """Jitted shard_map program, cached per (mesh, configs): rebuilding
     the wrapper per call would retrace on every solve (jit keys its cache
-    on the callable object)."""
-    body = partial(_sharded_smo_solve, cfg=cfg, kernel=kernel, ecfg=ecfg)
+    on the callable object). The body is ``solve_qp``'s driver on
+    (n_local,) shards of x/y/p/lo/hi/mask: scalars (b, n_iter,
+    converged, gap, n_active) come out replicated, alpha sharded."""
+    def sharded_solve(x, y, p, lo, hi, mask):
+        y = y.astype(jnp.float32)
+        mask = mask & (jnp.abs(y) > 0.5)  # padded signs are 0
+        eng = KE.ShardedKernelEngine(x.astype(jnp.float32), kernel, ecfg)
+        return _smo_solve(eng, _sharded_layout(axis), y, p, lo, hi,
+                          mask, cfg)
     spec, rep = P(axis), P()
     return jax.jit(jax.shard_map(
-        body, mesh=mesh, in_specs=(spec,) * 6,
+        sharded_solve, mesh=mesh, in_specs=(spec,) * 6,
         out_specs=SMOResult(spec, rep, rep, rep, rep, rep),
         check_vma=False))
 
@@ -867,24 +799,17 @@ def sharded_solve_qp(x: jax.Array,
     """Solve ONE box-constrained dual QP (the ``solve_qp`` problem) with
     the sample axis sharded over ``mesh.shape[axis]`` devices — the
     paper's data-parallel-within-one-QP MPI-CUDA configuration, for
-    problems a single device can't hold (or can't hold fast enough).
+    problems one device can't hold (or can't hold fast enough).
 
-    x / y / p / lo / hi / mask / alpha / f are sharded as equal
-    contiguous blocks (n is zero-padded to a multiple of the shard
-    count; padded rows are masked out and their alphas are identically
-    0). Working-set selection is globally exact: the cross-shard
-    reduction (``combine_selection``) is bit-identical to the unsharded
-    argmin/argmax, so any divergence from single-device ``solve_qp``
-    comes only from compiler-level float contraction differences in the
-    Gram rows (the SPMD partitioner may fuse dots differently). In
-    practice that means the SOLUTION matches — same support set,
-    |delta b| well under tol, identical predictions (enforced by
-    tests/test_sharded_smo.py) — while the iteration-by-iteration
-    trajectory can occasionally differ by a few pair updates on its way
-    to the same optimum.
-
-    Scalar-jit semantics apply per shard: adaptive shrinking
-    (``cfg.shrink_every``) and the LRU row cache both work here, unlike
+    The shard_map body runs ``solve_qp``'s own driver through the
+    sharded ``_Layout`` (module docstring). Every vector is split into
+    equal contiguous blocks (n zero-padded to a multiple of the shard
+    count; pad rows are masked and keep alpha = 0). Selection is
+    globally exact, so the solution matches single-device ``solve_qp``
+    — same support set, |delta b| well under tol, identical predictions
+    (tests/test_sharded_smo.py) — while the trajectory can differ by a
+    few pair updates where the SPMD partitioner contracts a Gram row's
+    dot differently. Shrinking and the LRU row cache work here, unlike
     the vmapped task-parallel path.
 
     Returns a host-layout SMOResult with alpha trimmed back to (n,).
@@ -897,10 +822,7 @@ def sharded_solve_qp(x: jax.Array,
     p = jnp.pad(jnp.asarray(p, jnp.float32), ((0, pad),))
     lo = jnp.broadcast_to(jnp.asarray(lo, jnp.float32), (n,))
     hi = jnp.broadcast_to(jnp.asarray(hi, jnp.float32), (n,))
-    if bool(jnp.any((lo > 0.0) | (hi < 0.0))):  # see solve_qp
-        raise ValueError(
-            "sharded_solve_qp initializes alpha = 0, which must be "
-            "feasible: need lo <= 0 <= hi elementwise")
+    _check_box(lo, hi, "sharded_solve_qp")
     lo = jnp.pad(lo, ((0, pad),))
     hi = jnp.pad(hi, ((0, pad),))
     m = (jnp.ones((n,), bool) if mask is None
@@ -955,10 +877,7 @@ def sharded_svr_smo(x: jax.Array,
     n = x.shape[0]
     x = jnp.asarray(x, jnp.float32)
     s, p, lo, hi = _svr_spec(y, epsilon, cfg.C)
-    x2 = jnp.concatenate([x, x], axis=0)
-    m2 = None
-    if mask is not None:
-        m2 = jnp.concatenate([mask, mask])
+    x2, m2 = _doubled(x, mask)
     r = sharded_solve_qp(x2, s, p, lo, hi, m2, mesh=mesh, axis=axis,
                          cfg=cfg, kernel=kernel, engine=engine)
     return _svr_result(r, n)

@@ -122,6 +122,21 @@ def test_shrinking_enabled_single_problem():
     _assert_equivalent(ref, got, x=x, yy=yy, kp=kp)
 
 
+@pytest.mark.requires_devices(4)
+@pytest.mark.parametrize("n_shards", [2, 4])
+def test_second_order_selection_matches_local(n_shards):
+    # WSS2's partner choice is the one collective argmax of the loop
+    # (local gain block-reduce + all_gather + first-occurrence combine)
+    x, yy = _binary_problem(384)
+    kp = K.resolve_gamma(K.KernelParams(), jnp.asarray(x))
+    cfg = smo.SMOConfig(selection="second")
+    ref = smo.binary_smo(jnp.asarray(x), jnp.asarray(yy), cfg=cfg,
+                         kernel=kp)
+    got = smo.sharded_binary_smo(x, yy, mesh=make_shard_mesh(n_shards),
+                                 cfg=cfg, kernel=kp)
+    _assert_equivalent(ref, got, x=x, yy=yy, kp=kp)
+
+
 @pytest.mark.slow
 @pytest.mark.requires_devices(4)
 def test_acceptance_n4096_rbf_4shards():
